@@ -11,8 +11,7 @@ full selection (scoring + softmax + top-m sampling) per method and K:
   * ``fused``  — the multi-block two-pass Pallas kernel
                  (``kernels.ops.heterosel_topm``): stats reduce, then blocks
                  stream through VMEM computing scores, probabilities and the
-                 in-kernel Gumbel-top-m — the (K,) probability vector never
-                 round-trips for selection.
+                 Gumbel-perturbed logits, cut by one XLA top-m.
   * ``sharded``— ``heterosel_topm_sharded``: the same kernel under
                  ``shard_map`` over a client device axis with cross-shard
                  collectives for the normalizer and the final top-m (equals
@@ -170,7 +169,7 @@ def main(quick: bool = True, *, ks=None, m_frac: float = 1e-3,
     """Callable from benchmarks/run.py (quick=smoke) or the CLI below."""
     ks = ks or ([1_000, 8_192] if quick
                 else [1_000, 10_000, 100_000, 1_000_000])
-    interpret = jax.default_backend() != "tpu"
+    interpret = kernel_ops.kernel_interpret()
     payload = run_sweep(ks, m_frac=m_frac, iters=iters,
                         interpret=interpret, smoke=quick)
     emit_bench_json("selector", payload)

@@ -128,7 +128,7 @@ def heterosel_select(
     With ``sel_cfg.use_fused_kernel`` the six score components + softmax +
     Gumbel-top-m sampling run as the two-pass multi-block Pallas kernel over
     the struct-of-arrays state (``kernels.score_select``) — the production
-    large-K path; interpret mode keeps it runnable (and tested) on CPU.
+    large-K path, compiled on TPU and interpreted on CPU (tests only).
     Additive form only. The in-kernel sampler draws the same Gumbel noise as
     :func:`sample_clients`, so for a given key the fused cohort matches the
     jnp path's.
@@ -148,7 +148,7 @@ def heterosel_select(
             state, jnp.asarray(round_idx, jnp.float32), tau,
             sel_cfg.num_selected, key, score_cfg,
             staleness_override=staleness_override,
-            interpret=jax.default_backend() != "tpu",
+            interpret=kernel_ops.kernel_interpret(),
         )
         mask = jnp.zeros((state.num_clients,), bool).at[selected].set(True)
         return mask, probs

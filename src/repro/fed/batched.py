@@ -105,10 +105,11 @@ def make_batched_local_train(
     vmapped = jax.vmap(step, in_axes=(None, 0))
     if mesh is not None and axes is not None and axes.pod is not None \
             and axes.pod in mesh.axis_names and axis_size(mesh, axes.pod) > 1:
-        vmapped = rules.shard_map_compat(
+        vmapped = jax.shard_map(
             vmapped, mesh=mesh,
             in_specs=(P(), P(axes.pod)),
             out_specs=P(axes.pod),
+            check_vma=False,
         )
     return jax.jit(vmapped)
 

@@ -426,6 +426,11 @@ class BatchedExecutor:
     def run_round(self, params, selected, rng, weights=None) -> CohortUpdates:
         stacked = fed_batched.gather_stacked_batches(
             self.data, selected, self.steps, self.fed.local_batch, rng)
+        if self.pod_size > 1 and len(selected) % self.pod_size == 0:
+            # Place the cohort client-sharded over 'pod' up front, so the
+            # shard_map step takes it as is (no reshard on entry).
+            stacked = fed_batched.shard_cohort(stacked, self.mesh,
+                                               self.mesh_axes)
         cohort = fed_batched.train_clients_batched(
             self._train, params, stacked, weights=weights,
             chunk=self.fed.client_chunk, pad_to=self.pod_size,

@@ -325,7 +325,7 @@ class HierarchicalEngine(FederatedEngine):
         seg_perm = jnp.asarray(perm)
         seg_sizes = jnp.asarray(self.partition.sizes, jnp.int32)
         score_cfg = self._score_cfg
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernel_ops.kernel_interpret()
 
         def segmented_probs(state, round_idx):
             sstate = jax.tree_util.tree_map(lambda x: x[seg_perm], state)

@@ -1,4 +1,6 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels, compiled by Mosaic on TPU (``score_select`` is
+compile-checked for v5e in tests/test_tpu_compile.py); ``interpret=True``
+runs them on CPU, for tests only (``ops.kernel_interpret`` decides).
 
 flash_attention — blockwise online-softmax attention (prefill hot spot)
 ssd_scan        — Mamba-2 SSD intra-chunk grouped matmuls

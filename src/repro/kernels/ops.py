@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These are the entry points a TPU deployment swaps in for the pure-jnp model
-paths (models default to jnp so CPU dry-runs/tests never require Mosaic;
-``interpret=True`` executes the kernel bodies on CPU for validation).
+paths (models default to jnp so CPU dry-runs/tests never require Mosaic).
+On the TPU the kernels are compiled by Mosaic; ``interpret=True`` executes
+the kernel bodies on CPU, for tests only — :func:`kernel_interpret` is the
+one place that picks between the two.
 """
 
 from __future__ import annotations
@@ -18,6 +20,20 @@ from repro.core.state import ClientState, score_inputs
 from repro.kernels import flash_attention as _fa
 from repro.kernels import score_select as _ss
 from repro.kernels import ssd_scan as _ssd
+
+
+def kernel_interpret() -> bool:
+    """Interpret mode for the default backend: ``True`` on ``cpu`` (tests),
+    ``False`` on ``tpu`` (compiled). Any other platform raises — there is
+    no silent interpreter fallback on an accelerator."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; "
+        f"the default backend is {platform!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
@@ -103,7 +119,7 @@ def heterosel_probs(state: ClientState, round_idx, tau,
 def heterosel_topm(state: ClientState, round_idx, tau, m: int, key,
                    cfg: HeteRoScoreConfig, *, staleness_override=None,
                    interpret: bool = False, block=None):
-    """Fused scoring + softmax + in-kernel Gumbel-top-m selection.
+    """Fused scoring + softmax + Gumbel-top-m selection.
 
     Returns ``(selected_idx (m,), probs, scores)``. For the same PRNG key
     the selection matches ``sample_clients`` over the jnp probabilities —

@@ -8,16 +8,17 @@ clients become a fused two-pass kernel over a real multi-block grid:
   * pass 1 (``_stats_kernel``): each grid step reduces one VMEM block of the
     stacked client metadata to five lane-slotted partials (loss min/max,
     Σ‖Δw‖² and observation count for the norm penalty, participation max);
-    the (nblocks, LANE) partial table is combined into global statistics
-    with a handful of O(nblocks) jnp reductions.
+    block i writes lanes ``[i·LANE, (i+1)·LANE)`` of a ``(1, nblocks·LANE)``
+    partial row (a (1, LANE) block is legal on the TPU at any block count),
+    combined into global statistics with a handful of O(nblocks) jnp
+    reductions.
   * pass 2 (``_select_kernel`` / ``_score_kernel``): blocks stream through
     VMEM again computing scores, block-local softmax exponentials with a
     flash-attention-style (m_b, l_b) normalizer merge, and — in the fused
-    selection variant — the per-block Gumbel-top-m candidates, so the (K,)
-    probability vector never has to be sorted or round-tripped to pick the
-    cohort. Per-block top-min(m, block) candidates are exact: any global
-    top-m element is beaten by at most m−1 others, hence survives its
-    block-local cut.
+    selection variant — the Gumbel-perturbed logits ``z + g``. Mosaic has no
+    in-kernel ``top_k``, so the Gumbel-top-m cut itself runs in XLA over
+    that (K,) perturbed vector: one ``lax.top_k``, the same ranking (and
+    the same cohort) as ``core.selection.sample_clients``.
 
 All (K,) operands travel as ONE stacked ``(NROWS, Kpad)`` array padded once
 (bf16 when the ClientState is bf16 — see ``core.state.to_bf16`` — so a
@@ -32,7 +33,8 @@ axis via shard_map, with cross-shard collectives for the min/max/mean
 statistics, the softmax normalizer, and the top-m candidate merge.
 
 VALIDATED against ``repro.core.scoring`` + softmax (the paper-faithful jnp
-implementation) in tests/test_kernels.py.
+implementation) in tests/test_kernels.py (interpret mode, CPU); every
+kernel here compiles for a TPU v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -60,7 +62,7 @@ NROWS = 9
 (SC_T, SC_TAU, SC_USEOV, SC_LMIN, SC_LMAX, SC_AVGSQ, SC_HMAX, SC_OFF,
  SC_KLIM) = range(9)
 
-# Lane slots of the (nblocks, LANE) pass-1 partial-statistics table.
+# Lane slots of each block's LANE-wide slice of the pass-1 partial row.
 (ST_LMIN, ST_LMAX, ST_SUMSQ, ST_NOBS, ST_HMAX) = range(5)
 
 
@@ -137,21 +139,21 @@ def _combine_stats(stats: jax.Array):
     return lmin, lmax, avgsq, hmax
 
 
-def _block_scores(rows, scal_ref, valid, cfg: HeteRoScoreConfig) -> jax.Array:
+def _block_scores(rows, sc, cfg: HeteRoScoreConfig) -> jax.Array:
     """Six score components + Eq (1) additive combination for one block.
 
-    ``rows(j)`` yields the (1, block) f32 view of stacked row j; the global
-    statistics arrive pre-reduced in the scalar lanes.
+    ``rows(j)`` yields the (1, block) f32 view of stacked row j and ``sc(j)``
+    scalar lane j; the global statistics arrive pre-reduced in those lanes.
     """
-    t = scal_ref[0, SC_T]
+    t = sc(SC_T)
     loss = rows(ROW_LOSS)
     loss2 = rows(ROW_LOSS2)
     has_loss = rows(ROW_HASL) > 0
     has_mom = rows(ROW_HASM) > 0
 
     # Eq (3): min-max normalized information value (neutral 0.5 if unseen)
-    lmin = scal_ref[0, SC_LMIN]
-    lmax = scal_ref[0, SC_LMAX]
+    lmin = sc(SC_LMIN)
+    lmax = sc(SC_LMAX)
     v = jnp.clip((loss - lmin) / (lmax - lmin + 1e-8), 0.0, 1.0)
     v = jnp.where(has_loss, v, 0.5)
 
@@ -164,10 +166,10 @@ def _block_scores(rows, scal_ref, valid, cfg: HeteRoScoreConfig) -> jax.Array:
     mom = 2.0 / (1.0 + jnp.exp(-5.0 * m)) - 0.5
 
     # Eq (6): fairness
-    fair = (1.0 + cfg.eta * rows(ROW_CNT) / scal_ref[0, SC_HMAX]) ** (-2)
+    fair = (1.0 + cfg.eta * rows(ROW_CNT) / sc(SC_HMAX)) ** (-2)
 
     # Eq (7): staleness — round-counter Δ or the async clock override row
-    use_ov = scal_ref[0, SC_USEOV]
+    use_ov = sc(SC_USEOV)
     delta = jnp.where(use_ov > 0,
                       jnp.maximum(rows(ROW_STALE), 0.0),
                       jnp.maximum(t - rows(ROW_LAST), 0.0))
@@ -175,7 +177,7 @@ def _block_scores(rows, scal_ref, valid, cfg: HeteRoScoreConfig) -> jax.Array:
     st = 1.0 + cfg.gamma * jnp.log1p(delta)
 
     # Eq (11): update-norm penalty
-    r = jnp.where(has_loss, rows(ROW_SQ) / (scal_ref[0, SC_AVGSQ] + 1e-8), 1.0)
+    r = jnp.where(has_loss, rows(ROW_SQ) / (sc(SC_AVGSQ) + 1e-8), 1.0)
     npen = 1.0 - cfg.alpha * (2.0 / (1.0 + jnp.exp(-3.0 * r)) - 1.0)
 
     # Eq (1) additive combination (Eqs 8–10 shift the modulating factors)
@@ -195,39 +197,31 @@ def _score_body(state_ref, scal_ref, *, cfg, block):
     klim = scal_ref[0, SC_KLIM].astype(jnp.int32)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) + i * block + off
     valid = col < klim
-    s = _block_scores(_rows_fn(state_ref), scal_ref, valid, cfg)
+    s = _block_scores(_rows_fn(state_ref), lambda j: scal_ref[0, j], cfg)
     z = jnp.where(valid, s / scal_ref[0, SC_TAU], -BIG)
     m_b = jnp.max(z)
     e = jnp.where(valid, jnp.exp(z - m_b), 0.0)
-    return s, z, e, m_b, col
+    return s, z, e, m_b
 
 
 def _score_kernel(state_ref, scal_ref, scores_ref, e_ref, part_ref, *,
                   cfg: HeteRoScoreConfig, block: int):
-    s, _, e, m_b, _ = _score_body(state_ref, scal_ref, cfg=cfg, block=block)
+    s, _, e, m_b = _score_body(state_ref, scal_ref, cfg=cfg, block=block)
     scores_ref[...] = s
     e_ref[...] = e
     part_ref[...] = _lane_put(LANE, 0, m_b) + _lane_put(LANE, 1, jnp.sum(e))
 
 
 def _select_kernel(state_ref, scal_ref, gumbel_ref, scores_ref, e_ref,
-                   part_ref, cval_ref, cidx_ref, *,
-                   cfg: HeteRoScoreConfig, block: int, mb_pad: int):
-    """Pass 2 + in-kernel Gumbel-top-m: emits per-block selection candidates
-    (perturbed logit + global client id) alongside the softmax pieces, so
-    sampling never sorts the (K,) probability vector at the jnp level."""
-    i = pl.program_id(0)
-    s, z, e, m_b, col = _score_body(state_ref, scal_ref, cfg=cfg, block=block)
+                   part_ref, pert_ref, *, cfg: HeteRoScoreConfig, block: int):
+    """Pass 2 + the Gumbel-perturbed logits the top-m cut ranks (in XLA)."""
+    s, z, e, m_b = _score_body(state_ref, scal_ref, cfg=cfg, block=block)
     scores_ref[...] = s
     e_ref[...] = e
     part_ref[...] = _lane_put(LANE, 0, m_b) + _lane_put(LANE, 1, jnp.sum(e))
-    # Gumbel-perturbed unnormalized logits: ranking z + g equals ranking
-    # log p + g (constant −logsumexp shift), so no normalizer is needed.
-    pert = z + gumbel_ref[...].astype(jnp.float32)
-    vals, loc = jax.lax.top_k(pert, mb_pad)
-    cval_ref[...] = vals
-    off = scal_ref[0, SC_OFF].astype(jnp.int32)
-    cidx_ref[...] = loc + i * block + off
+    # Ranking z + g equals ranking log p + g (constant −logsumexp shift), so
+    # no normalizer is needed; padding lanes keep z = −BIG and never rank.
+    pert_ref[...] = z + gumbel_ref[...].astype(jnp.float32)
 
 
 def _segment_kernel(state_ref, size_ref, scal_ref, probs_ref, scores_ref, *,
@@ -236,7 +230,8 @@ def _segment_kernel(state_ref, size_ref, scal_ref, probs_ref, scores_ref, *,
 
     Per-edge statistics (loss min/max, norm average, participation max) are
     reduced over that edge's ``size_e`` valid rows only — exactly what the
-    per-edge jnp path computes on its gathered sub-state.
+    per-edge jnp path computes on its gathered sub-state. ``size_ref`` is
+    edge e's LANE-wide slice of the sizes row (the size sits in lane 0).
     """
     col = jax.lax.broadcasted_iota(jnp.int32, (1, seg), 1)
     valid = col < size_ref[0, 0].astype(jnp.int32)
@@ -244,23 +239,21 @@ def _segment_kernel(state_ref, size_ref, scal_ref, probs_ref, scores_ref, *,
     loss = rows(ROW_LOSS)
     sq = rows(ROW_SQ)
     obs = valid & (rows(ROW_HASL) > 0)
-    lmin = jnp.min(jnp.where(obs, loss, BIG))
-    lmax = jnp.max(jnp.where(obs, loss, -BIG))
-    avgsq = jnp.sum(jnp.where(obs, sq, 0.0)) / jnp.maximum(
-        jnp.sum(jnp.where(obs, 1.0, 0.0)), 1.0)
-    hmax = jnp.maximum(jnp.max(jnp.where(valid, rows(ROW_CNT), 0.0)), 1.0)
-    scal = scal_ref[...]
-    scal = (scal
-            + _lane_put(LANE, SC_LMIN, lmin) + _lane_put(LANE, SC_LMAX, lmax)
-            + _lane_put(LANE, SC_AVGSQ, avgsq) + _lane_put(LANE, SC_HMAX, hmax))
+    edge_stats = {
+        SC_LMIN: jnp.min(jnp.where(obs, loss, BIG)),
+        SC_LMAX: jnp.max(jnp.where(obs, loss, -BIG)),
+        SC_AVGSQ: jnp.sum(jnp.where(obs, sq, 0.0)) / jnp.maximum(
+            jnp.sum(jnp.where(obs, 1.0, 0.0)), 1.0),
+        SC_HMAX: jnp.maximum(
+            jnp.max(jnp.where(valid, rows(ROW_CNT), 0.0)), 1.0),
+    }
 
-    class _Scal:  # duck-typed scalar view for _block_scores
-        def __getitem__(self, idx):
-            return scal[idx]
+    def sc(j):
+        return edge_stats[j] if j in edge_stats else scal_ref[0, j]
 
-    s = _block_scores(rows, _Scal(), valid, cfg)
+    s = _block_scores(rows, sc, cfg)
     scores_ref[...] = s
-    z = jnp.where(valid, s / scal[0, SC_TAU], -BIG)
+    z = jnp.where(valid, s / sc(SC_TAU), -BIG)
     e = jnp.where(valid, jnp.exp(z - jnp.max(z)), 0.0)
     probs_ref[...] = e / jnp.maximum(jnp.sum(e), 1e-30)
 
@@ -288,10 +281,33 @@ def _run_stats(stacked, scal0, *, nblocks, block, interpret):
         grid=(nblocks,),
         in_specs=[pl.BlockSpec((NROWS, block), lambda i: (0, i)),
                   pl.BlockSpec((1, LANE), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, LANE), jnp.float32),
+        out_specs=pl.BlockSpec((1, LANE), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, nblocks * LANE), jnp.float32),
         interpret=interpret,
-    )(stacked, scal0)
+    )(stacked, scal0).reshape(nblocks, LANE)
+
+
+def _run_select(stacked, scal, gpad, *, cfg, nblocks, block, interpret):
+    """Pass 2 with the Gumbel row: ``(scores, e, part, pert)``, where
+    ``part`` is the (nblocks, LANE) (m_b, l_b) table and the others are
+    ``(1, nblocks·block)`` rows."""
+    kpad = nblocks * block
+    kernel = functools.partial(_select_kernel, cfg=cfg, block=block)
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
+    scores, e, part, pert = pl.pallas_call(
+        kernel,
+        grid=(nblocks,),
+        in_specs=[pl.BlockSpec((NROWS, block), lambda i: (0, i)),
+                  pl.BlockSpec((1, LANE), lambda i: (0, 0)),
+                  row],
+        out_specs=[row, row, pl.BlockSpec((1, LANE), lambda i: (0, i)), row],
+        out_shape=[jax.ShapeDtypeStruct((1, kpad), jnp.float32),
+                   jax.ShapeDtypeStruct((1, kpad), jnp.float32),
+                   jax.ShapeDtypeStruct((1, nblocks * LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((1, kpad), jnp.float32)],
+        interpret=interpret,
+    )(stacked, scal, gpad)
+    return scores, e, part.reshape(nblocks, LANE), pert
 
 
 def fused_score_probs(
@@ -329,13 +345,13 @@ def fused_score_probs(
                   pl.BlockSpec((1, LANE), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((1, blk), lambda i: (0, i)),
                    pl.BlockSpec((1, blk), lambda i: (0, i)),
-                   pl.BlockSpec((1, LANE), lambda i: (i, 0))],
+                   pl.BlockSpec((1, LANE), lambda i: (0, i))],
         out_shape=[jax.ShapeDtypeStruct((1, kpad), jnp.float32),
                    jax.ShapeDtypeStruct((1, kpad), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, LANE), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, nblocks * LANE), jnp.float32)],
         interpret=interpret,
     )(stacked, scal)
-    probs = _normalize(e.reshape(-1), part, nblocks, blk)
+    probs = _normalize(e.reshape(-1), part.reshape(nblocks, LANE), nblocks, blk)
     return probs[:k], scores.reshape(-1)[:k]
 
 
@@ -352,7 +368,7 @@ def fused_score_select(
     int32. The Gumbel noise is drawn host-side with the exact shape/dtype
     ``core.selection.sample_clients`` uses, so for the same key the fused
     selection matches the jnp path (ranking z + g ≡ ranking log p + g).
-    Per-block top-min(m, block) candidates keep the global top-m exact.
+    The kernel emits the perturbed logits; one XLA ``top_k`` cuts them.
     """
     k = loss_prev.shape[0]
     blk, nblocks, kpad = _layout(k, block)
@@ -369,30 +385,11 @@ def fused_score_select(
 
     gumbel = jax.random.gumbel(key, (k,), jnp.float32)
     gpad = jnp.pad(gumbel, (0, kpad - k)).reshape(1, kpad)
-    mb_pad = -(-min(m, blk) // LANE) * LANE
-
-    kernel = functools.partial(_select_kernel, cfg=cfg, block=blk, mb_pad=mb_pad)
-    scores, e, part, cval, cidx = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((NROWS, blk), lambda i: (0, i)),
-                  pl.BlockSpec((1, LANE), lambda i: (0, 0)),
-                  pl.BlockSpec((1, blk), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, blk), lambda i: (0, i)),
-                   pl.BlockSpec((1, blk), lambda i: (0, i)),
-                   pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-                   pl.BlockSpec((1, mb_pad), lambda i: (i, 0)),
-                   pl.BlockSpec((1, mb_pad), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, kpad), jnp.float32),
-                   jax.ShapeDtypeStruct((1, kpad), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, mb_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, mb_pad), jnp.int32)],
-        interpret=interpret,
-    )(stacked, scal, gpad)
+    scores, e, part, pert = _run_select(stacked, scal, gpad, cfg=cfg,
+                                        nblocks=nblocks, block=blk,
+                                        interpret=interpret)
     probs = _normalize(e.reshape(-1), part, nblocks, blk)[:k]
-    _, pos = jax.lax.top_k(cval.reshape(-1), m)
-    selected = cidx.reshape(-1)[pos]
+    _, selected = jax.lax.top_k(pert.reshape(-1), m)
     return selected, probs, scores.reshape(-1)[:k]
 
 
@@ -422,8 +419,9 @@ def segmented_score_probs(
     rows = (loss_prev, loss_prev2, label_js, part_count, last_selected,
             update_sqnorm, has_loss, has_momentum)
     stacked = _pack(rows, staleness_override, k_total, k_total)
+    # Edge e's size in lane 0 of its LANE-wide slice of one (1, E·LANE) row.
     sizes_op = jnp.zeros((num_edges, LANE), jnp.float32).at[:, 0].set(
-        jnp.asarray(sizes, jnp.float32))
+        jnp.asarray(sizes, jnp.float32)).reshape(1, num_edges * LANE)
     t = jnp.asarray(round_idx, jnp.float32)
     use_ov = 0.0 if staleness_override is None else 1.0
     # Stat lanes start at zero — filled per-edge inside the kernel.
@@ -434,7 +432,7 @@ def segmented_score_probs(
         kernel,
         grid=(num_edges,),
         in_specs=[pl.BlockSpec((NROWS, seg), lambda e: (0, e)),
-                  pl.BlockSpec((1, LANE), lambda e: (e, 0)),
+                  pl.BlockSpec((1, LANE), lambda e: (0, e)),
                   pl.BlockSpec((1, LANE), lambda e: (0, 0))],
         out_specs=[pl.BlockSpec((1, seg), lambda e: (0, e)),
                    pl.BlockSpec((1, seg), lambda e: (0, e))],
@@ -461,7 +459,7 @@ def sharded_score_select(
     the per-shard top-m candidates for the final cut. Returns
     ``(selected_idx, probs, scores)`` like the single-device path.
     """
-    from repro.sharding.rules import axis_size, shard_map_compat
+    from repro.sharding.rules import axis_size
     from jax.sharding import PartitionSpec as P
 
     ndev = max(axis_size(mesh, axis), 1)
@@ -478,7 +476,7 @@ def sharded_score_select(
     assert local_pad == local_k or local_pad > local_k
     t = jnp.asarray(round_idx, jnp.float32)
     use_ov = 0.0 if staleness_override is None else 1.0
-    mb_pad = -(-min(m, blk) // LANE) * LANE
+    m_local = min(m, local_k)
 
     def shard_body(stacked_l, gpad_l):
         # Per-shard column offset; global validity limit is K everywhere,
@@ -502,26 +500,9 @@ def sharded_score_select(
         hmax = jnp.maximum(hmax, 1.0)
         scal = _scalar_row(t, tau, use_ov, lmin, lmax, avgsq, hmax, off, klim)
 
-        kernel = functools.partial(_select_kernel, cfg=cfg, block=blk,
-                                   mb_pad=mb_pad)
-        scores_l, e_l, part_l, cval_l, cidx_l = pl.pallas_call(
-            kernel,
-            grid=(nblocks,),
-            in_specs=[pl.BlockSpec((NROWS, blk), lambda i: (0, i)),
-                      pl.BlockSpec((1, LANE), lambda i: (0, 0)),
-                      pl.BlockSpec((1, blk), lambda i: (0, i))],
-            out_specs=[pl.BlockSpec((1, blk), lambda i: (0, i)),
-                       pl.BlockSpec((1, blk), lambda i: (0, i)),
-                       pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-                       pl.BlockSpec((1, mb_pad), lambda i: (i, 0)),
-                       pl.BlockSpec((1, mb_pad), lambda i: (i, 0))],
-            out_shape=[jax.ShapeDtypeStruct((1, local_pad), jnp.float32),
-                       jax.ShapeDtypeStruct((1, local_pad), jnp.float32),
-                       jax.ShapeDtypeStruct((nblocks, LANE), jnp.float32),
-                       jax.ShapeDtypeStruct((nblocks, mb_pad), jnp.float32),
-                       jax.ShapeDtypeStruct((nblocks, mb_pad), jnp.int32)],
-            interpret=interpret,
-        )(stacked_l, scal, gpad_l)
+        scores_l, e_l, part_l, pert_l = _run_select(
+            stacked_l, scal, gpad_l, cfg=cfg, nblocks=nblocks, block=blk,
+            interpret=interpret)
 
         # Cross-shard softmax normalizer merge (flash-attention style).
         m_b = part_l[:, 0]
@@ -532,18 +513,23 @@ def sharded_score_select(
         scale = jnp.exp(m_b - mglob)
         probs_l = (e_l.reshape(nblocks, blk) * scale[:, None] / lglob
                    ).reshape(-1)[:local_k]
-        # Candidate merge: every shard sees all candidates → identical
-        # replicated top-m on every device.
-        cval_all = jax.lax.all_gather(cval_l.reshape(-1), axis).reshape(-1)
-        cidx_all = jax.lax.all_gather(cidx_l.reshape(-1), axis).reshape(-1)
+        # Per-shard top-m candidates (any global top-m element is beaten by
+        # at most m−1 others, so it survives its shard's cut), then the
+        # merge: every shard sees all candidates → identical replicated
+        # top-m on every device.
+        cval_l, loc = jax.lax.top_k(pert_l.reshape(-1)[:local_k], m_local)
+        cidx_l = loc + idx * local_k
+        cval_all = jax.lax.all_gather(cval_l, axis).reshape(-1)
+        cidx_all = jax.lax.all_gather(cidx_l, axis).reshape(-1)
         _, pos = jax.lax.top_k(cval_all, m)
         selected = cidx_all[pos]
         return selected, probs_l.reshape(1, local_k), \
             scores_l.reshape(-1)[:local_k].reshape(1, local_k)
 
-    selected, probs, scores = shard_map_compat(
+    selected, probs, scores = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis)),
         out_specs=(P(), P(None, axis), P(None, axis)),
+        check_vma=False,
     )(stacked, gpad)
     return selected, probs.reshape(-1)[:k], scores.reshape(-1)[:k]

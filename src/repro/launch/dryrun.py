@@ -27,7 +27,7 @@ import jax
 
 from repro.configs.registry import ARCHS, ASSIGNED, get_config, get_shape
 from repro.configs.shapes import SHAPES
-from repro.launch.mesh import make_production_mesh, mesh_chip_count, mesh_context
+from repro.launch.mesh import make_production_mesh, mesh_chip_count
 from repro.launch.steps import build_plan, depth_variant, outer_trips
 from repro.models.layers import set_probe_mode
 from repro.roofline import hlo as roofline
@@ -62,7 +62,7 @@ def save_results(results: Dict, multi_pod: bool) -> None:
 
 
 def _compile_plan(plan, mesh):
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             plan.fn,
             in_shardings=plan.in_shardings,

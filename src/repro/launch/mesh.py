@@ -8,20 +8,14 @@ import; tests use ``make_test_mesh`` with whatever devices exist.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-
-try:  # jax ≥ 0.5 exposes explicit axis types; older versions are Auto-only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,19 +28,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_test_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Small CPU mesh for tests (requires forced host device count)."""
     return _mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Portable global-mesh context: ``jax.set_mesh`` (jax ≥ 0.6),
-    ``jax.sharding.use_mesh`` (0.5.x), else the legacy ``with mesh:``
-    context manager — all make the mesh current for sharding inference."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
 
 
 def mesh_chip_count(mesh) -> int:

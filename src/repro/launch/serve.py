@@ -33,6 +33,7 @@ import jax
 from repro.ckpt import (list_federated_rounds, read_federated_meta,
                         restore_checkpoint, restore_federated_round)
 from repro.configs.registry import get_config, smoke_variant
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.models import vlm as vlm_mod
 from repro.serve import (ModelServer, TrafficConfig, TrafficSimulator,
@@ -87,8 +88,9 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = get_config(args.arch)
-    if args.smoke or len(jax.devices()) == 1:
+    if args.smoke:
         cfg = smoke_variant(cfg)
+    enable_compile_cache()
     model = build_model(cfg)
     if not model.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only — no decode serving")

@@ -1,12 +1,16 @@
 """Production federated-training launcher.
 
-On a real TPU fleet this process runs once per host; ``jax.devices()`` shows
-the fleet and ``make_production_mesh`` builds the (data, model) — or
-(pod, data, model) — mesh. On this CPU container it runs the same code over
-a reduced architecture so the launcher itself is exercised end-to-end.
+Trains ``--arch`` at its full published width on whatever devices JAX
+sees (one TPU v5e chip holds the paper's ResNet-18 cohort); only ``--smoke``
+swaps in the reduced variant. Defaults are the paper's federation: K=12
+clients, participation 0.5, Dirichlet α=0.1, FedProx μ=0.1, client
+lr 0.01, HeteRo-Select, batched client execution.
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
-        --rounds 5 --clients 8 [--smoke] [--ckpt-dir /tmp/ckpt]
+    PYTHONPATH=src python -m repro.launch.train --arch resnet18-cifar10 \
+        --rounds 5 [--smoke] [--ckpt-dir /tmp/ckpt]
+
+``main(argv)`` returns the run's ``FLResult``, so a script can drive the
+launcher itself (``chip_smoke.py`` does).
 
 The control plane (HeteRo-Select scoring over client metadata) always runs
 on the host exactly as in the paper; the data plane (FedProx local steps)
@@ -33,32 +37,41 @@ from __future__ import annotations
 
 import argparse
 import math
+from typing import Optional, Sequence
 
 import jax
+import numpy as np
 
 from repro.configs.base import FedConfig
 from repro.configs.registry import get_config, smoke_variant
 from repro.data import make_lm_data, make_vision_data
 from repro.fed import (AsyncConfig, CheckpointHook, FederatedSpec,
-                       HierarchyConfig)
+                       FLResult, HierarchyConfig)
 from repro.fed.availability import SystemProfile
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.ckpt import save_checkpoint
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> FLResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="resnet18-cifar10")
     ap.add_argument("--rounds", type=int, default=10)
     ap.add_argument("--clients", type=int, default=12)
     ap.add_argument("--participation", type=float, default=0.5)
     ap.add_argument("--mu", type=float, default=0.1)
-    ap.add_argument("--lr", type=float, default=0.3)
+    ap.add_argument("--lr", type=float, default=FedConfig.lr,
+                    help="client SGD step size (default: the paper's; the "
+                         "full-width ResNet-18 diverges at 0.3)")
     ap.add_argument("--selector", default="heterosel")
     ap.add_argument("--aggregator", default="fedavg",
                     choices=["fedavg", "fedavg_weighted", "fedavgm"])
+    ap.add_argument("--client-execution", default="batched",
+                    choices=["batched", "sequential"],
+                    help="whole cohort in one vmapped call, or one call per "
+                         "client (the numerical reference)")
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced smoke variant of --arch (CPU)")
+                    help="use the reduced smoke variant of --arch")
     ap.add_argument("--ckpt-dir", default=None,
                     help="enable mid-run checkpoint/resume under this dir "
                          "(works with every --round-policy / --topology)")
@@ -96,12 +109,12 @@ def main() -> None:
                     help="write telemetry artifacts under this dir "
                          "(trace.jsonl stream, Perfetto-loadable trace.json, "
                          "report.md/report.json); implies --telemetry")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"[train] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch)
-    if args.smoke or len(jax.devices()) == 1:
+    if args.smoke:
         cfg = smoke_variant(cfg)
-        print(f"[train] single-device/smoke mode: {cfg.name}")
 
     if args.topology == "hierarchical" and args.edges < 1:
         ap.error("--topology hierarchical requires --edges E (≥ 1)")
@@ -113,6 +126,7 @@ def main() -> None:
     fed = FedConfig(num_clients=args.clients, participation=args.participation,
                     rounds=args.rounds, local_epochs=2, local_batch=16,
                     lr=args.lr, mu=args.mu, selector=args.selector, seed=0,
+                    client_execution=args.client_execution,
                     topology=args.topology, edge_count=args.edges,
                     edge_budget=args.edge_budget)
     if cfg.family == "resnet":
@@ -121,6 +135,10 @@ def main() -> None:
         data = make_lm_data(fed, vocab=cfg.vocab_size, seq_len=32)
 
     model = build_model(cfg)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0))))
+    print(f"[train] {cfg.name}: d_model={cfg.d_model}, layers={cfg.num_layers}, "
+          f"{n_params / 1e6:.2f} M params; devices: {jax.devices()}")
     hooks = []
     telemetry_hook = None
     if args.telemetry or args.trace_dir:
@@ -179,6 +197,7 @@ def main() -> None:
         path = save_checkpoint(args.ckpt_dir, res.params, step=fed.rounds,
                                extra=res.summary())
         print("checkpoint:", path)
+    return res
 
 
 if __name__ == "__main__":

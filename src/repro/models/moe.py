@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.sharding.rules import shard_map_compat
 from repro.models import attention as attn
 from repro.models.layers import (
     DEFAULT_DTYPE,
@@ -224,11 +223,12 @@ def moe_ffn(
         fn = functools.partial(_moe_ffn_a2a, cfg,
                                data_axis=data_axis, model_axis=model_axis,
                                dsz=dsz)
-        return shard_map_compat(
+        return jax.shard_map(
             lambda lp_, x_: fn(lp_, x_),
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(data_axis, None, None), P()),
+            check_vma=False,
         )({k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")}, x)
 
     # Tokens shard over 'data' only when the batch dim divides it; tiny-batch
@@ -250,11 +250,12 @@ def moe_ffn(
     out_specs = (P(x_axis, None, None), P())
 
     fn = functools.partial(_moe_ffn_local, cfg, model_axis=model_axis, fsdp_axis=fsdp_axis)
-    return shard_map_compat(
+    return jax.shard_map(
         lambda lp_, x_: fn(lp_, x_),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
+        check_vma=False,
     )(
         {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")}, x
     )

@@ -52,23 +52,6 @@ class MeshAxes:
 POD_AXES = MeshAxes(pod="pod")
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    jax ≥ 0.6 exposes ``jax.shard_map`` (replica check flag ``check_vma``);
-    older releases only have ``jax.experimental.shard_map.shard_map``
-    (flag ``check_rep``). Both checks are disabled — our bodies use
-    collectives whose replication the checker cannot always prove.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def axis_size(mesh: Mesh, name: Optional[str]) -> int:
     """Size of a mesh axis; 1 for ``None``, 0 when absent from the mesh.
 

@@ -22,7 +22,7 @@ def run_child(body: str, timeout: int = 560) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
         from repro.launch.steps import build_plan
         from repro.configs.registry import get_config, smoke_variant, get_shape
         import dataclasses
@@ -56,7 +56,7 @@ def test_single_pod_small_mesh_compiles(arch, shape_name):
         shape = dataclasses.replace(get_shape("{shape_name}"),
                                     seq_len=64, global_batch=8)
         plan = build_plan(cfg, shape, mesh, fsdp=False)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                                out_shardings=plan.out_shardings,
                                donate_argnums=plan.donate_argnums).lower(*plan.args).compile()
@@ -89,7 +89,7 @@ def test_multi_pod_round_step_semantics():
             "tokens": jax.random.randint(key, (2, 4, 32), 0, cfg.vocab_size),
             "labels": jax.random.randint(key, (2, 4, 32), 0, cfg.vocab_size),
         }
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             out, loss = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                                 out_shardings=plan.out_shardings)(stacked, p, batch)
 
@@ -137,7 +137,7 @@ def test_moe_a2a_matches_gather_and_local():
         lp = M.init_moe_ffn(key, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
         ref, _ = M._moe_ffn_local(cfg, lp, x, model_axis=None, fsdp_axis=None)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             g, _ = jax.jit(lambda l, xx: M.moe_ffn(cfg, l, xx, mesh=mesh))(lp, x)
             cfg2 = dataclasses.replace(cfg, moe_impl="a2a")
             a, _ = jax.jit(lambda l, xx: M.moe_ffn(cfg2, l, xx, mesh=mesh))(lp, x)

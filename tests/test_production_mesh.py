@@ -19,7 +19,7 @@ def run_child(body: str, timeout: int = 560) -> str:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
         import jax
-        from repro.launch.mesh import make_production_mesh, mesh_chip_count, mesh_context
+        from repro.launch.mesh import make_production_mesh, mesh_chip_count
         from repro.launch.steps import build_plan
         from repro.configs.registry import get_config, get_shape
         from repro.sharding.rules import needs_fsdp
@@ -39,7 +39,7 @@ def test_single_pod_production_compile():
         cfg = get_config("qwen2-0.5b")
         plan = build_plan(cfg, get_shape("train_4k"), mesh,
                           fsdp=needs_fsdp(cfg, 16))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                                out_shardings=plan.out_shardings,
                                donate_argnums=plan.donate_argnums
@@ -59,7 +59,7 @@ def test_multi_pod_production_compile():
         cfg = get_config("mamba2-370m")
         plan = build_plan(cfg, get_shape("train_4k"), mesh, multi_pod=True,
                           fsdp=needs_fsdp(cfg, 16))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                                out_shardings=plan.out_shardings,
                                donate_argnums=plan.donate_argnums
