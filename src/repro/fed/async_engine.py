@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any, List, Optional
 
 import numpy as np
@@ -77,6 +76,7 @@ from repro.fed.engine import (
     RoundContext,
     register_aggregator,
 )
+from repro.obs.phases import phase
 
 # Staleness reported for never-contacted clients (clipped by Eq 7's T_max).
 NEVER_STALE = 1.0e6
@@ -365,86 +365,81 @@ class AsyncFederatedEngine(FederatedEngine):
         dispatch_time = self.clock.now
 
         # 1. Dispatch: over-select on clock-measured staleness, skip busy.
-        t0 = time.perf_counter()
-        self.key, sk = jax.random.split(self.key)
-        mask, _ = self._select_async(sk, self.state, jnp.int32(t),
-                                     self._staleness_override())
-        mask_np = np.asarray(mask) & ~self._in_flight
-        selected = np.flatnonzero(mask_np)
-        t1 = time.perf_counter()
+        tr = self.tracer
+        with phase("select", ctx, tr, args={"round": t}):
+            self.key, sk = jax.random.split(self.key)
+            mask, _ = self._select_async(sk, self.state, jnp.int32(t),
+                                         self._staleness_override())
+            mask_np = np.asarray(mask) & ~self._in_flight
+            selected = np.flatnonzero(mask_np)
 
         # 2. Train the dispatch cohort in one executor call; hold the
         #    updates back and schedule their completions on the clock.
-        if len(selected):
-            weights = self.aggregator.cohort_weights(selected, spec.data)
-            w_np = (np.ones(len(selected)) if weights is None
-                    else np.asarray(weights, np.float64))
-            cohort = self.executor.run_round(self.params, selected, self.rng,
-                                             weights=None)
-            self.wire_total += cohort.wire_bytes
-            self.raw_total += cohort.raw_bytes
-            lat = self.latency.sample(selected, self.rng)
-            losses = np.asarray(cohort.mean_loss, np.float32)
-            sqnorms = np.asarray(cohort.update_sqnorm, np.float32)
-            for i, c in enumerate(selected):
-                payload = PendingUpdate(
-                    delta=self._client_delta(cohort, i),
-                    loss=float(losses[i]), sqnorm=float(sqnorms[i]),
-                    weight=float(w_np[i]))
-                self.clock.schedule(lat[i], c, t, payload)
-            self._in_flight[selected] = True
-
-        t2 = time.perf_counter()
+        with phase("execute", ctx, tr,
+                   args={"round": t, "dispatched": int(len(selected))}):
+            if len(selected):
+                weights = self.aggregator.cohort_weights(selected, spec.data)
+                w_np = (np.ones(len(selected)) if weights is None
+                        else np.asarray(weights, np.float64))
+                cohort = self.executor.run_round(self.params, selected, self.rng,
+                                                 weights=None)
+                self.wire_total += cohort.wire_bytes
+                self.raw_total += cohort.raw_bytes
+                lat = self.latency.sample(selected, self.rng)
+                losses = np.asarray(cohort.mean_loss, np.float32)
+                sqnorms = np.asarray(cohort.update_sqnorm, np.float32)
+                for i, c in enumerate(selected):
+                    payload = PendingUpdate(
+                        delta=self._client_delta(cohort, i),
+                        loss=float(losses[i]), sqnorm=float(sqnorms[i]),
+                        weight=float(w_np[i]))
+                    self.clock.schedule(lat[i], c, t, payload)
+                self._in_flight[selected] = True
 
         # 3. Close the round at the deadline; carry late updates forward.
-        kept, dropped = drain_due_arrivals(self.clock, acfg, t, dispatch_time,
-                                           self._in_flight)
-        self.updates_dropped += dropped
+        # 4. Buffered aggregation of the arrivals.
+        with phase("aggregate", ctx, tr, args={"round": t}) as agg:
+            kept, dropped = drain_due_arrivals(self.clock, acfg, t, dispatch_time,
+                                               self._in_flight)
+            self.updates_dropped += dropped
+            stale = np.asarray([t - ev.dispatch_round for ev in kept], np.float32)
+            n_stragglers = sum(1 for ev in kept if ev.dispatch_round < t)
+            agg.args.update(arrivals=int(len(kept)), stragglers=n_stragglers)
+            if kept:
+                agg_cohort = CohortUpdates(
+                    mean_loss=np.asarray([ev.payload.loss for ev in kept], np.float32),
+                    update_sqnorm=np.asarray([ev.payload.sqnorm for ev in kept],
+                                             np.float32),
+                    delta_list=[ev.payload.delta for ev in kept],
+                    staleness=stale,
+                    weights=np.asarray([ev.payload.weight for ev in kept],
+                                       np.float32),
+                )
+                self.params = self.aggregator.reduce(self.params, agg_cohort)
 
-        # 4. Buffered aggregation + metadata fold for the arrivals.
-        stale = np.asarray([t - ev.dispatch_round for ev in kept], np.float32)
-        if kept:
-            agg_cohort = CohortUpdates(
-                mean_loss=np.asarray([ev.payload.loss for ev in kept], np.float32),
-                update_sqnorm=np.asarray([ev.payload.sqnorm for ev in kept],
-                                         np.float32),
-                delta_list=[ev.payload.delta for ev in kept],
-                staleness=stale,
-                weights=np.asarray([ev.payload.weight for ev in kept],
-                                   np.float32),
-            )
-            self.params = self.aggregator.reduce(self.params, agg_cohort)
-
-            arr_ids = np.asarray([ev.client for ev in kept], np.int64)
-            arr_mask = np.zeros(spec.data.num_clients, bool)
-            arr_mask[arr_ids] = True
+        # 5. Metadata fold for the arrivals.
+        with phase("state_update", recorder=tr, args={"round": t}):
             obs_loss = np.zeros(spec.data.num_clients, np.float32)
             obs_sqnorm = np.zeros(spec.data.num_clients, np.float32)
-            obs_loss[arr_ids] = agg_cohort.mean_loss
-            obs_sqnorm[arr_ids] = agg_cohort.update_sqnorm
-            self.state = update_client_state(
-                self.state,
-                round_idx=jnp.int32(t),
-                selected_mask=jnp.asarray(arr_mask),
-                observed_loss=jnp.asarray(obs_loss),
-                observed_sqnorm=jnp.asarray(obs_sqnorm),
-            )
-            self._last_contact[arr_ids] = dispatch_time
-        else:
-            arr_ids = np.asarray([], np.int64)
-            obs_loss = np.zeros(spec.data.num_clients, np.float32)
-            obs_sqnorm = np.zeros(spec.data.num_clients, np.float32)
+            if kept:
+                arr_ids = np.asarray([ev.client for ev in kept], np.int64)
+                arr_mask = np.zeros(spec.data.num_clients, bool)
+                arr_mask[arr_ids] = True
+                obs_loss[arr_ids] = agg_cohort.mean_loss
+                obs_sqnorm[arr_ids] = agg_cohort.update_sqnorm
+                self.state = update_client_state(
+                    self.state,
+                    round_idx=jnp.int32(t),
+                    selected_mask=jnp.asarray(arr_mask),
+                    observed_loss=jnp.asarray(obs_loss),
+                    observed_sqnorm=jnp.asarray(obs_sqnorm),
+                )
+                self._last_contact[arr_ids] = dispatch_time
 
-        t3 = time.perf_counter()
-        ctx.select_ms = (t1 - t0) * 1e3
-        ctx.execute_ms = (t2 - t1) * 1e3
-        ctx.aggregate_ms = (t3 - t2) * 1e3
-
-        # 5. Clock bookkeeping + the usual round tail.
+        # 6. Clock bookkeeping + the usual round tail.
         duration = self.clock.now - dispatch_time
         self._dur_sum += duration
         self._dur_n += 1
-        n_stragglers = sum(1 for ev in kept if ev.dispatch_round < t)
         self.stragglers_carried += n_stragglers
         self.wall_clock.append(self.clock.now)
         self.round_staleness.append(float(stale.mean()) if len(stale) else 0.0)
@@ -456,18 +451,8 @@ class AsyncFederatedEngine(FederatedEngine):
         ctx.sim_time = self.clock.now
         ctx.num_arrivals = len(kept)
         ctx.num_stragglers = n_stragglers
-        enabled = self.tracer.enabled
-        te0 = time.perf_counter() if enabled else 0.0
-        ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        if enabled:
-            tr = self.tracer
-            tr.wall_span("select", t0, t1, args={"round": t})
-            tr.wall_span("execute", t1, t2,
-                         args={"round": t, "dispatched": int(len(selected))})
-            tr.wall_span("aggregate", t2, t3,
-                         args={"round": t, "arrivals": int(len(kept)),
-                               "stragglers": n_stragglers})
-            tr.wall_span("eval", te0, time.perf_counter(), args={"round": t})
+        with phase("eval", recorder=tr, args={"round": t}):
+            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
         ctx.train_loss = (float(np.mean([ev.payload.loss for ev in kept]))
                           if kept else 0.0)
         self._rounds_done = t + 1

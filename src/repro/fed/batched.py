@@ -38,6 +38,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.fed import server as fed_server
 from repro.fed.client import LocalResult, LossFn, local_train
+from repro.obs.phases import phase
 from repro.sharding import rules
 from repro.sharding.rules import MeshAxes, axis_size
 
@@ -163,7 +164,8 @@ def train_clients_batched(
 
     if not chunk or (chunk >= m and m % max(pad_to, 1) == 0):
         res = batched_train(params, stacked_batches)
-        avg = fed_server.fedavg_fused(res.params, weights)
+        with phase("aggregate"):
+            avg = fed_server.fedavg_fused(res.params, weights)
         return CohortResult(
             avg_params=avg,
             stacked_params=res.params if keep_client_params else None,
@@ -190,14 +192,17 @@ def train_clients_batched(
             stacked_batches,
         )
         res = batched_train(params, sl)
-        part = fed_server.weighted_sum_stacked(res.params, w[start:start + chunk])
-        acc = part if acc is None else jax.tree_util.tree_map(jnp.add, acc, part)
+        with phase("aggregate"):
+            part = fed_server.weighted_sum_stacked(res.params,
+                                                   w[start:start + chunk])
+            acc = part if acc is None else jax.tree_util.tree_map(jnp.add, acc, part)
         losses.append(res.mean_loss)
         sqnorms.append(res.update_sqnorm)
-    avg = jax.tree_util.tree_map(
-        lambda s, p: s.astype(p.dtype), acc,
-        jax.tree_util.tree_map(lambda x: x[0], res.params),
-    )
+    with phase("aggregate"):
+        avg = jax.tree_util.tree_map(
+            lambda s, p: s.astype(p.dtype), acc,
+            jax.tree_util.tree_map(lambda x: x[0], res.params),
+        )
     return CohortResult(
         avg_params=avg,
         stacked_params=None,

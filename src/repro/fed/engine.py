@@ -76,6 +76,7 @@ from repro.fed import client as fed_client
 from repro.fed import compression as fed_comp
 from repro.fed import server as fed_server
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.phases import current_recorder, phase, round_step
 from repro.obs.trace import NULL_TRACER
 from repro.sharding.rules import MeshAxes, axis_size
 
@@ -424,8 +425,9 @@ class BatchedExecutor:
             mesh=self.mesh, axes=self.mesh_axes)
 
     def run_round(self, params, selected, rng, weights=None) -> CohortUpdates:
-        stacked = fed_batched.gather_stacked_batches(
-            self.data, selected, self.steps, self.fed.local_batch, rng)
+        with phase("gather", recorder=current_recorder()):
+            stacked = fed_batched.gather_stacked_batches(
+                self.data, selected, self.steps, self.fed.local_batch, rng)
         if self.pod_size > 1 and len(selected) % self.pod_size == 0:
             # Place the cohort client-sharded over 'pod' up front, so the
             # shard_map step takes it as is (no reshard on entry).
@@ -465,9 +467,11 @@ class SequentialExecutor:
         param_list: List[Any] = []
         losses = np.zeros(m, np.float32)
         sqnorms = np.zeros(m, np.float32)
+        recorder = current_recorder()
         for i, k in enumerate(selected):
-            batches = self.data.client_batches(
-                int(k), self.steps, self.fed.local_batch, rng)
+            with phase("gather", recorder=recorder):
+                batches = self.data.client_batches(
+                    int(k), self.steps, self.fed.local_batch, rng)
             res = self._train(params, batches)
             losses[i] = float(res.mean_loss)
             sqnorms[i] = float(res.update_sqnorm)
@@ -569,7 +573,8 @@ class CompressedExecutor:
         c, stats = fed_comp.quantize_int8_stacked(delta)
         decoded = fed_comp.dequantize_int8_stacked(c)
         rebuilt = fed_comp.tree_apply_delta(anchor, decoded)
-        avg = fed_server.fedavg_fused(rebuilt, cohort.weights)
+        with phase("aggregate"):
+            avg = fed_server.fedavg_fused(rebuilt, cohort.weights)
         return dataclasses.replace(
             cohort, avg_params=avg, stacked_params=rebuilt,
             wire_bytes=stats.wire_bytes, raw_bytes=stats.raw_bytes)
@@ -1198,11 +1203,12 @@ class FederatedEngine:
         eval_batch = spec.data.eval_batch()
         for t in range(self.start_round, fed.rounds):
             ctx.round_idx = t
-            for h in self.hooks:
-                h.on_round_start(ctx)
-            self._run_round(ctx, t, eval_batch)
-            for h in self.hooks:
-                h.on_round_end(ctx)
+            with round_step(t):
+                for h in self.hooks:
+                    h.on_round_start(ctx)
+                self._run_round(ctx, t, eval_batch)
+                for h in self.hooks:
+                    h.on_round_end(ctx)
 
         extras: Dict[str, Any] = {}
         for h in self.hooks:
@@ -1211,49 +1217,39 @@ class FederatedEngine:
         return self._result(extras)
 
     def _run_round(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec, fed = self.spec, self.spec.fed
-        t0 = time.perf_counter()
-        self.key, sk = jax.random.split(self.key)
-        mask, _ = self._select(sk, self.state, jnp.int32(t))
-        mask_np = np.asarray(mask)  # device sync — the selection phase ends
-        selected = np.flatnonzero(mask_np)
-        t1 = time.perf_counter()
+        spec, tr = self.spec, self.tracer
+        with phase("select", ctx, tr, args={"round": t}):
+            self.key, sk = jax.random.split(self.key)
+            mask, _ = self._select(sk, self.state, jnp.int32(t))
+            mask_np = np.asarray(mask)  # device sync — the selection phase ends
+            selected = np.flatnonzero(mask_np)
 
-        weights = self.aggregator.cohort_weights(selected, spec.data)
-        cohort = self.executor.run_round(self.params, selected, self.rng,
-                                         weights=weights)
-        t2 = time.perf_counter()
-        self.params = self.aggregator.reduce(self.params, cohort)
-        self.wire_total += cohort.wire_bytes
-        self.raw_total += cohort.raw_bytes
-        t3 = time.perf_counter()
-        ctx.select_ms = (t1 - t0) * 1e3
-        ctx.execute_ms = (t2 - t1) * 1e3
-        ctx.aggregate_ms = (t3 - t2) * 1e3
+        with phase("execute", ctx, tr,
+                   args={"round": t, "cohort": int(len(selected))}):
+            weights = self.aggregator.cohort_weights(selected, spec.data)
+            cohort = self.executor.run_round(self.params, selected, self.rng,
+                                             weights=weights)
+        with phase("aggregate", ctx, tr, args={"round": t}):
+            self.params = self.aggregator.reduce(self.params, cohort)
+            self.wire_total += cohort.wire_bytes
+            self.raw_total += cohort.raw_bytes
 
-        obs_loss, obs_sqnorm = self._dense_observations(selected, cohort)
-        self.state = update_client_state(
-            self.state,
-            round_idx=jnp.int32(t),
-            selected_mask=jnp.asarray(mask_np),
-            observed_loss=jnp.asarray(obs_loss),
-            observed_sqnorm=jnp.asarray(obs_sqnorm),
-        )
+        with phase("state_update", recorder=tr, args={"round": t}):
+            obs_loss, obs_sqnorm = self._dense_observations(selected, cohort)
+            self.state = update_client_state(
+                self.state,
+                round_idx=jnp.int32(t),
+                selected_mask=jnp.asarray(mask_np),
+                observed_loss=jnp.asarray(obs_loss),
+                observed_sqnorm=jnp.asarray(obs_sqnorm),
+            )
 
         ctx.mask = mask_np
         ctx.selected = selected
         ctx.obs_loss = obs_loss
         ctx.obs_sqnorm = obs_sqnorm
-        enabled = self.tracer.enabled
-        te0 = time.perf_counter() if enabled else 0.0
-        ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        if enabled:
-            tr = self.tracer
-            tr.wall_span("select", t0, t1, args={"round": t})
-            tr.wall_span("execute", t1, t2,
-                         args={"round": t, "cohort": int(len(selected))})
-            tr.wall_span("aggregate", t2, t3, args={"round": t})
-            tr.wall_span("eval", te0, time.perf_counter(), args={"round": t})
+        with phase("eval", recorder=tr, args={"round": t}):
+            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
         ctx.train_loss = float(np.mean(obs_loss[selected])) if len(selected) else 0.0
         ctx.sim_time = float(t + 1)  # sync rounds cost "1" on the time axis
         self._rounds_done = t + 1

@@ -88,7 +88,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -127,6 +126,7 @@ from repro.fed.engine import (
     WeightedFedAvg,
 )
 from repro.fed.partition import EdgePartition, partition_edges
+from repro.obs.phases import phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -479,28 +479,24 @@ class HierarchicalEngine(FederatedEngine):
     def _inner_execute(self, picks: List[tuple]) -> List[EdgeCohort]:
         """One executor call per selected edge cohort."""
         out: List[EdgeCohort] = []
-        enabled = self.tracer.enabled
         for e, sel_global in picks:
-            te = time.perf_counter() if enabled else 0.0
-            weights = self.aggregator.cohort_weights(sel_global, self.spec.data)
-            cohort = self.executor.run_round(self.params, sel_global, self.rng,
-                                             weights=weights)
-            self.wire_total += cohort.wire_bytes
-            self.raw_total += cohort.raw_bytes
-            ew = (float(len(sel_global)) if weights is None
-                  else float(np.sum(np.asarray(weights, np.float64))))
-            out.append(EdgeCohort(
-                edge=e,
-                selected=sel_global,
-                losses=np.asarray(cohort.mean_loss, np.float32),
-                sqnorms=np.asarray(cohort.update_sqnorm, np.float32),
-                weight=ew,
-                avg_params=self.aggregator._mean(cohort),
-            ))
-            if enabled:
-                self.tracer.wall_span(
-                    "edge_execute", te, time.perf_counter(), track="edges",
-                    args={"edge": int(e), "cohort": int(len(sel_global))})
+            with phase("edge_execute", recorder=self.tracer, track="edges",
+                       args={"edge": int(e), "cohort": int(len(sel_global))}):
+                weights = self.aggregator.cohort_weights(sel_global,
+                                                         self.spec.data)
+                cohort = self.executor.run_round(self.params, sel_global,
+                                                 self.rng, weights=weights)
+                self.wire_total += cohort.wire_bytes
+                self.raw_total += cohort.raw_bytes
+                ew = (float(len(sel_global)) if weights is None
+                      else float(np.sum(np.asarray(weights, np.float64))))
+                losses = np.asarray(cohort.mean_loss, np.float32)
+                sqnorms = np.asarray(cohort.update_sqnorm, np.float32)
+                with phase("aggregate"):
+                    avg_params = self.aggregator._mean(cohort)
+                out.append(EdgeCohort(edge=e, selected=sel_global, losses=losses,
+                                      sqnorms=sqnorms, weight=ew,
+                                      avg_params=avg_params))
         return out
 
     # -- observation fold (shared by both policies) ------------------------
@@ -561,123 +557,101 @@ class HierarchicalEngine(FederatedEngine):
         else:
             self._run_round_sync(ctx, t, eval_batch)
 
+    def _select_phase(self, ctx: RoundContext, t: int) -> List[tuple]:
+        """Outer (edge) then inner (per-edge client) selection; returns the
+        (edge, global cohort ids) picks."""
+        tr = self.tracer
+        with phase("select", ctx, tr, args={"round": t}):
+            self.key, sk = jax.random.split(self.key)
+            with phase("outer_select", recorder=tr, args={"round": t}) as outer:
+                active = self._choose_edges(sk, t, self._idle_edges())
+                outer.args["edges"] = int(len(active))
+            with phase("inner_select", recorder=tr, args={"round": t}):
+                picks = self._inner_select(active, self._inner_keys(sk), t)
+        return picks
+
     def _run_round_sync(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec = self.spec
-        t0 = time.perf_counter()
-        self.key, sk = jax.random.split(self.key)
-        active = self._choose_edges(sk, t, self._idle_edges())
-        ts = time.perf_counter()  # outer (edge) selection ends here
-        picks = self._inner_select(active, self._inner_keys(sk), t)
-        t1 = time.perf_counter()
-        cohorts = self._inner_execute(picks)
-        t2 = time.perf_counter()
+        spec, tr = self.spec, self.tracer
+        picks = self._select_phase(ctx, t)
+        with phase("execute", ctx, tr, args={"round": t, "edges": int(len(picks))}):
+            cohorts = self._inner_execute(picks)
 
-        if len(cohorts) == 1:
-            # The weighted mean of one edge aggregate is that aggregate —
-            # taken bitwise, which is what pins the E=1 flat-equivalence
-            # contract (no f32 round-trip through the delta form).
-            self.params = cohorts[0].avg_params
-        elif cohorts:
-            deltas = [fed_server.params_delta_f32(c.avg_params, self.params)
-                      for c in cohorts]
-            w = jnp.asarray([c.weight for c in cohorts], jnp.float32)
-            self.params = fed_server.apply_weighted_deltas(
-                self.params, deltas, w)
-        self.cloud_uploads.append(len(cohorts))
-        t3 = time.perf_counter()
-        ctx.select_ms = (t1 - t0) * 1e3
-        ctx.execute_ms = (t2 - t1) * 1e3
-        ctx.aggregate_ms = (t3 - t2) * 1e3
+        with phase("aggregate", ctx, tr,
+                   args={"round": t, "cloud_uploads": int(len(cohorts))}):
+            if len(cohorts) == 1:
+                # The weighted mean of one edge aggregate is that aggregate —
+                # taken bitwise, which is what pins the E=1 flat-equivalence
+                # contract (no f32 round-trip through the delta form).
+                self.params = cohorts[0].avg_params
+            elif cohorts:
+                deltas = [fed_server.params_delta_f32(c.avg_params, self.params)
+                          for c in cohorts]
+                w = jnp.asarray([c.weight for c in cohorts], jnp.float32)
+                self.params = fed_server.apply_weighted_deltas(
+                    self.params, deltas, w)
+            self.cloud_uploads.append(len(cohorts))
 
-        self._fold_observations(ctx, t, cohorts)
-        enabled = self.tracer.enabled
-        te0 = time.perf_counter() if enabled else 0.0
-        ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        if enabled:
-            tr = self.tracer
-            tr.wall_span("outer_select", t0, ts,
-                         args={"round": t, "edges": int(len(active))})
-            tr.wall_span("inner_select", ts, t1, args={"round": t})
-            tr.wall_span("execute", t1, t2,
-                         args={"round": t, "edges": int(len(picks))})
-            tr.wall_span("aggregate", t2, t3,
-                         args={"round": t, "cloud_uploads": int(len(cohorts))})
-            tr.wall_span("eval", te0, time.perf_counter(), args={"round": t})
+        with phase("state_update", recorder=tr, args={"round": t}):
+            self._fold_observations(ctx, t, cohorts)
+        with phase("eval", recorder=tr, args={"round": t}):
+            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
         ctx.sim_time = float(t + 1)  # sync rounds cost "1" on the time axis
         self._rounds_done = t + 1
 
     def _run_round_async(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec, acfg = self.spec, self.acfg
+        spec, acfg, tr = self.spec, self.acfg, self.tracer
         dispatch_time = self.clock.now
 
         # 1.–2. Dispatch idle edges; each trains now but its aggregate
         # arrives at the cloud after the max of its cohort's latencies
         # (the edge is an internal barrier).
-        t0 = time.perf_counter()
-        self.key, sk = jax.random.split(self.key)
-        active = self._choose_edges(sk, t, self._idle_edges())
-        ts = time.perf_counter()  # outer (edge) selection ends here
-        picks = self._inner_select(active, self._inner_keys(sk), t)
-        t1 = time.perf_counter()
+        picks = self._select_phase(ctx, t)
         dispatched = np.zeros(spec.data.num_clients, bool)
-        for c in self._inner_execute(picks):
-            c.delta = fed_server.params_delta_f32(c.avg_params, self.params)
-            c.avg_params = None  # the anchor-relative delta is what travels
-            lat = float(self.latency.sample(c.selected, self.rng).max())
-            self.clock.schedule(lat, c.edge, t, payload=c)
-            self._edge_in_flight[c.edge] = True
-            dispatched[c.selected] = True
-        t2 = time.perf_counter()
+        with phase("execute", ctx, tr, args={"round": t, "edges": int(len(picks))}):
+            for c in self._inner_execute(picks):
+                c.delta = fed_server.params_delta_f32(c.avg_params, self.params)
+                c.avg_params = None  # the anchor-relative delta is what travels
+                lat = float(self.latency.sample(c.selected, self.rng).max())
+                self.clock.schedule(lat, c.edge, t, payload=c)
+                self._edge_in_flight[c.edge] = True
+                dispatched[c.selected] = True
 
         # 3. Close the cloud round at the deadline (the shared flat-async
         # semantics — drain_due_arrivals); straggler edges carry forward as
         # stale arrivals.
-        kept, dropped = drain_due_arrivals(self.clock, acfg, t, dispatch_time,
-                                           self._edge_in_flight)
-        self.updates_dropped += dropped
-
         # 4. Buffered aggregation of the arrived edge aggregates.
-        stale = np.asarray([t - ev.dispatch_round for ev in kept], np.float32)
-        arrivals = [ev.payload for ev in kept]
-        if kept:
-            agg_cohort = CohortUpdates(
-                mean_loss=np.asarray([c.losses.mean() for c in arrivals],
-                                     np.float32),
-                update_sqnorm=np.asarray([c.sqnorms.mean() for c in arrivals],
+        with phase("aggregate", ctx, tr, args={"round": t}) as agg:
+            kept, dropped = drain_due_arrivals(self.clock, acfg, t, dispatch_time,
+                                               self._edge_in_flight)
+            self.updates_dropped += dropped
+            stale = np.asarray([t - ev.dispatch_round for ev in kept], np.float32)
+            n_stragglers = sum(1 for ev in kept if ev.dispatch_round < t)
+            agg.args.update(cloud_uploads=int(len(kept)), stragglers=n_stragglers)
+            arrivals = [ev.payload for ev in kept]
+            if kept:
+                agg_cohort = CohortUpdates(
+                    mean_loss=np.asarray([c.losses.mean() for c in arrivals],
                                          np.float32),
-                delta_list=[c.delta for c in arrivals],
-                staleness=stale,
-                weights=np.asarray([c.weight for c in arrivals], np.float32),
-            )
-            self.params = self.aggregator.reduce(self.params, agg_cohort)
-        self.cloud_uploads.append(len(kept))
-        t3 = time.perf_counter()
-        ctx.select_ms = (t1 - t0) * 1e3
-        ctx.execute_ms = (t2 - t1) * 1e3
-        ctx.aggregate_ms = (t3 - t2) * 1e3
-        self._fold_observations(ctx, t, arrivals, dispatched_mask=dispatched)
+                    update_sqnorm=np.asarray([c.sqnorms.mean() for c in arrivals],
+                                             np.float32),
+                    delta_list=[c.delta for c in arrivals],
+                    staleness=stale,
+                    weights=np.asarray([c.weight for c in arrivals], np.float32),
+                )
+                self.params = self.aggregator.reduce(self.params, agg_cohort)
+            self.cloud_uploads.append(len(kept))
 
-        n_stragglers = sum(1 for ev in kept if ev.dispatch_round < t)
+        with phase("state_update", recorder=tr, args={"round": t}):
+            self._fold_observations(ctx, t, arrivals, dispatched_mask=dispatched)
+
         self.stragglers_carried += n_stragglers
         self.wall_clock.append(self.clock.now)
         self.round_staleness.append(float(stale.mean()) if len(stale) else 0.0)
         ctx.sim_time = self.clock.now
         ctx.num_arrivals = len(kept)
         ctx.num_stragglers = n_stragglers
-        enabled = self.tracer.enabled
-        te0 = time.perf_counter() if enabled else 0.0
-        ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        if enabled:
-            tr = self.tracer
-            tr.wall_span("outer_select", t0, ts,
-                         args={"round": t, "edges": int(len(active))})
-            tr.wall_span("inner_select", ts, t1, args={"round": t})
-            tr.wall_span("execute", t1, t2,
-                         args={"round": t, "edges": int(len(picks))})
-            tr.wall_span("aggregate", t2, t3,
-                         args={"round": t, "cloud_uploads": int(len(kept)),
-                               "stragglers": n_stragglers})
-            tr.wall_span("eval", te0, time.perf_counter(), args={"round": t})
+        with phase("eval", recorder=tr, args={"round": t}):
+            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
         self._rounds_done = t + 1
 
     def _result(self, extras) -> FLResult:

@@ -1,8 +1,9 @@
 """Dual-timeline trace recording: virtual sim-time + host wall-time spans.
 
 ``TraceRecorder`` is the flight recorder behind the telemetry plane
-(``repro.obs``): every engine phase (select / execute / aggregate / eval,
-the hierarchical outer/inner selection split and per-edge executor calls),
+(``repro.obs``): every engine phase (select / execute / gather / aggregate
+/ state_update / eval, the hierarchical outer/inner selection split and
+per-edge executor calls, all emitted by ``obs.phases.phase``),
 every virtual-clock event (client completions, serve-channel arrivals),
 checkpoint saves and publish/serve waves record onto one of two timelines:
 
