@@ -22,8 +22,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import tiny_model
 from repro.configs.base import FedConfig
-from repro.configs.registry import get_config, smoke_variant
 from repro.core.scoring import HeteRoScoreConfig, compute_scores, staleness_factor
 from repro.core.selection import SelectorConfig, make_async_selector
 from repro.core.state import init_client_state, staleness as state_staleness
@@ -40,16 +40,10 @@ from repro.fed import (
     staleness_weights,
 )
 from repro.fed import server as fs
-from repro.models import build_model
-
-
-def tiny_model():
-    return build_model(dataclasses.replace(
-        smoke_variant(get_config("resnet18-cifar10")), d_model=8))
 
 
 @pytest.fixture(scope="module")
-def quickstart_setup():
+def async_quickstart_setup():
     """The acceptance-criterion scale: examples/quickstart.py federation."""
     fed = FedConfig(num_clients=12, participation=0.5, rounds=6,
                     local_epochs=2, local_batch=16, lr=0.3, mu=0.1,
@@ -59,7 +53,7 @@ def quickstart_setup():
 
 
 @pytest.fixture(scope="module")
-def small_setup():
+def async_small_setup():
     fed = FedConfig(num_clients=6, participation=0.5, rounds=4, local_epochs=1,
                     local_batch=8, lr=0.2, mu=0.1, dirichlet_alpha=0.1, seed=0)
     data = make_vision_data(fed, train_per_class=24, test_per_class=8, noise=0.3)
@@ -236,7 +230,7 @@ class ArrivalStats(RoundHook):
 
 
 class TestSyncAsyncEquivalence:
-    def test_equal_latencies_infinite_deadline(self, quickstart_setup):
+    def test_equal_latencies_infinite_deadline(self, async_quickstart_setup):
         """Acceptance: quickstart-scale async == sync ±1% at equal latencies.
 
         With uniform latencies, deadline=∞ and ε=0 every dispatch cohort
@@ -245,7 +239,7 @@ class TestSyncAsyncEquivalence:
         selection histories are identical, and aggregation differs only by
         the delta-form float reassociation.
         """
-        fed, data, model = quickstart_setup
+        fed, data, model = async_quickstart_setup
         sync = FederatedSpec(model, fed, data, selector="heterosel",
                              steps_per_round=2).build().run()
         eng = FederatedSpec(model, fed, data, selector="heterosel",
@@ -266,17 +260,17 @@ class TestSyncAsyncEquivalence:
         np.testing.assert_array_equal(res.round_staleness,
                                       np.zeros(fed.rounds))
 
-    def test_sync_results_carry_no_wall_clock(self, small_setup):
-        fed, data, model = small_setup
+    def test_sync_results_carry_no_wall_clock(self, async_small_setup):
+        fed, data, model = async_small_setup
         res = FederatedSpec(model, fed, data, selector="heterosel",
                             steps_per_round=2).build().run()
         assert res.wall_clock is None and res.round_staleness is None
 
-    def test_pallas_selector_history_matches_jnp_async(self, small_setup):
+    def test_pallas_selector_history_matches_jnp_async(self, async_small_setup):
         """selector='heterosel_pallas' on the async engine: identical
         selection history to the jnp selector (fused kernel in interpret
         mode on CPU), with real stragglers in the mix."""
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=3)
         mult = np.ones(fed.num_clients)
         mult[0] = 3.0
@@ -293,11 +287,11 @@ class TestSyncAsyncEquivalence:
 
 
 class TestDeadlineAndStragglers:
-    def test_straggler_carries_forward_as_stale_arrival(self, small_setup):
+    def test_straggler_carries_forward_as_stale_arrival(self, async_small_setup):
         """10× straggler + finite deadline: its update misses the dispatch
         round, stays in flight (never re-dispatched), and aggregates later
         with staleness ≥ 1 — conservation: nothing is silently dropped."""
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=6)
         mult = np.ones(fed.num_clients)
         mult[0] = 5.0
@@ -321,8 +315,8 @@ class TestDeadlineAndStragglers:
         # deadline-paced: round closes never before dispatch+deadline spacing
         assert res.wall_clock[-1] < fed.rounds * 5.0  # ≪ straggler-paced sync
 
-    def test_over_selection_dispatches_m_over(self, small_setup):
-        fed, data, model = small_setup
+    def test_over_selection_dispatches_m_over(self, async_small_setup):
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=2)
         eng = FederatedSpec(
             model, fed, data, selector="heterosel", steps_per_round=1,
@@ -334,8 +328,8 @@ class TestDeadlineAndStragglers:
         res = eng.run()
         assert res.selected_history[0].sum() == eng.m_over
 
-    def test_max_staleness_drops_ancient_updates(self, small_setup):
-        fed, data, model = small_setup
+    def test_max_staleness_drops_ancient_updates(self, async_small_setup):
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=5)
         mult = np.ones(fed.num_clients)
         mult[0] = 4.0
@@ -348,11 +342,11 @@ class TestDeadlineAndStragglers:
         eng.run()
         assert eng.updates_dropped >= 1
 
-    def test_min_updates_counts_post_filter_arrivals(self, small_setup):
+    def test_min_updates_counts_post_filter_arrivals(self, async_small_setup):
         """A staleness-dropped arrival must not satisfy min_updates: the
         round keeps extending until an aggregatable update exists, so no
         round ever aggregates nothing while updates are still pending."""
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=5)
         mult = np.full(fed.num_clients, 4.0)  # everyone misses the deadline
         stats = ArrivalStats()
@@ -365,8 +359,8 @@ class TestDeadlineAndStragglers:
         eng.run()
         assert min(stats.arrivals) >= 1
 
-    def test_sequential_executor_async(self, small_setup):
-        fed, data, model = small_setup
+    def test_sequential_executor_async(self, async_small_setup):
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=2)
         res = FederatedSpec(model, fed, data, selector="heterosel",
                             steps_per_round=1, executor="sequential",
@@ -374,8 +368,8 @@ class TestDeadlineAndStragglers:
         assert np.isfinite(res.accuracy).all()
         assert len(res.wall_clock) == fed.rounds
 
-    def test_availability_composes_with_async(self, small_setup):
-        fed, data, model = small_setup
+    def test_availability_composes_with_async(self, async_small_setup):
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=3)
         avail = np.ones((fed.rounds, fed.num_clients), bool)
         avail[:, 1] = False
@@ -394,46 +388,46 @@ class TestAsyncConfigAndCompat:
         with pytest.raises(ValueError, match="base_latency"):
             AsyncConfig(base_latency=0.0)
 
-    def test_unknown_round_policy_raises(self, small_setup):
-        fed, data, model = small_setup
+    def test_unknown_round_policy_raises(self, async_small_setup):
+        fed, data, model = async_small_setup
         with pytest.raises(ValueError, match="round_policy"):
             FederatedSpec(model, fed, data, round_policy="semi").build()
 
-    def test_async_knobs_with_sync_policy_raise(self, small_setup):
+    def test_async_knobs_with_sync_policy_raise(self, async_small_setup):
         """system/async_cfg must not be silently ignored by the sync engine."""
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         with pytest.raises(ValueError, match="round_policy='async'"):
             FederatedSpec(model, fed, data,
                           system=np.ones(fed.num_clients)).build()
         with pytest.raises(ValueError, match="round_policy='async'"):
             FederatedSpec(model, fed, data, async_cfg=AsyncConfig()).build()
 
-    def test_non_delta_aggregator_raises(self, small_setup):
-        fed, data, model = small_setup
+    def test_non_delta_aggregator_raises(self, async_small_setup):
+        fed, data, model = async_small_setup
         with pytest.raises(ValueError, match="supports_deltas"):
             FederatedSpec(model, fed, data, round_policy="async",
                           aggregator="fedavgm").build()
 
-    def test_chunked_batched_raises(self, small_setup):
-        fed, data, model = small_setup
+    def test_chunked_batched_raises(self, async_small_setup):
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, client_chunk=2)
         with pytest.raises(ExecutorCompatError, match="client_chunk"):
             FederatedSpec(model, fed, data, round_policy="async").build()
 
-    def test_bad_system_shape_raises(self, small_setup):
-        fed, data, model = small_setup
+    def test_bad_system_shape_raises(self, async_small_setup):
+        fed, data, model = async_small_setup
         with pytest.raises(ValueError, match="multipliers"):
             FederatedSpec(model, fed, data, round_policy="async",
                           system=np.ones(3)).build()
 
-    def test_checkpointing_supported(self, small_setup, tmp_path):
+    def test_checkpointing_supported(self, async_small_setup, tmp_path):
         """Async runs checkpoint: the snapshot carries the engine kind, the
         clock state and the in-flight vector (full kill/resume bitwise
         equality is pinned by tests/test_resume_matrix.py)."""
         from repro.ckpt import latest_federated_round, read_federated_meta
         from repro.fed import CheckpointHook
 
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, rounds=2)
         mult = np.ones(fed.num_clients)
         mult[0] = 5.0
@@ -452,9 +446,9 @@ class TestAsyncConfigAndCompat:
         pending = {str(e["seq"]) for e in meta["extra"]["clock"]["events"]}
         assert pending == set(meta["extra"]["pending"])
 
-    def test_fedconfig_one_field_switch(self, small_setup):
+    def test_fedconfig_one_field_switch(self, async_small_setup):
         """The one-config-field mode switch the issue asks for."""
-        fed, data, model = small_setup
+        fed, data, model = async_small_setup
         fed = dataclasses.replace(fed, round_policy="async", rounds=2)
         eng = FederatedSpec(model, fed, data, selector="heterosel",
                             steps_per_round=1).build()

@@ -21,8 +21,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import shared_run, tiny_model
 from repro.configs.base import FedConfig
-from repro.configs.registry import get_config, smoke_variant
 from repro.core.scoring import HeteRoScoreConfig, compute_scores
 from repro.core.selection import SelectorConfig, dynamic_temperature, make_selector
 from repro.core.state import (
@@ -37,7 +37,6 @@ from repro.fed import client as fc
 from repro.fed import server as fs
 from repro.fed import run_federated
 from repro.kernels.score_select import fused_score_probs
-from repro.models import build_model
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -124,9 +123,7 @@ def vision_setup():
     fed = FedConfig(num_clients=12, participation=0.5, rounds=6, local_epochs=2,
                     local_batch=16, lr=0.3, mu=0.1, dirichlet_alpha=0.1, seed=0)
     data = make_vision_data(fed, train_per_class=48, test_per_class=16, noise=0.3)
-    model = build_model(dataclasses.replace(
-        smoke_variant(get_config("resnet18-cifar10")), d_model=8))
-    return fed, data, model
+    return fed, data, tiny_model()
 
 
 class TestEndToEndEquivalence:
@@ -134,8 +131,8 @@ class TestEndToEndEquivalence:
         """ISSUE 2 acceptance: same seed ⇒ identical selection histories and
         accuracies to float tolerance on the paper model at K=12."""
         fed, data, model = vision_setup
-        rb = run_federated(model, fed, data, steps_per_round=4,
-                           client_execution="batched")
+        rb = shared_run(run_federated, model, fed, data, steps_per_round=4,
+                        client_execution="batched")
         rs = run_federated(model, fed, data, steps_per_round=4,
                            client_execution="sequential")
         assert (rb.selected_history == rs.selected_history).all()
@@ -145,8 +142,8 @@ class TestEndToEndEquivalence:
 
     def test_chunked_run_matches_batched(self, vision_setup):
         fed, data, model = vision_setup
-        rb = run_federated(model, fed, data, steps_per_round=4,
-                           client_execution="batched")
+        rb = shared_run(run_federated, model, fed, data, steps_per_round=4,
+                        client_execution="batched")
         rc = run_federated(model, dataclasses.replace(fed, client_chunk=4), data,
                            steps_per_round=4, client_execution="batched")
         assert (rb.selected_history == rc.selected_history).all()
@@ -215,6 +212,7 @@ class TestLargeKPallasPath:
         assert share >= data.label_dists[int(sel[0])].max() - 0.1
 
 
+@pytest.mark.time_limit(600)  # above the child's own 560 s timeout
 def test_pod_shard_map_matches_single_device():
     """The mesh path shards the cohort's client axis over 'pod' and must
     reproduce the single-device vmap result (subprocess: forced 8 devices)."""

@@ -238,10 +238,14 @@ if HAVE_HYPOTHESIS:
                 ).map(lambda v: jnp.asarray(
                     np.asarray(v, dtype).reshape(s))))
 
-        f32 = arr(np.float32, st.floats(-1e30, 1e30, width=32,
+        # width=32 bounds must be float32 values: the nearest ones to ±1e30
+        # and ±3e38 give the same range
+        f32_1e30 = float(np.float32(1e30))
+        f32_3e38 = float(np.float32(3e38))
+        f32 = arr(np.float32, st.floats(-f32_1e30, f32_1e30, width=32,
                                         allow_nan=False))
         i32 = arr(np.int32, st.integers(NEVER, 2**31 - 1))
-        bf16 = arr(np.float32, st.floats(-3e38, 3e38, width=32,
+        bf16 = arr(np.float32, st.floats(-f32_3e38, f32_3e38, width=32,
                                          allow_nan=False)
                    ).map(lambda a: a.astype(jnp.bfloat16))
         return st.one_of(f32, i32, bf16)

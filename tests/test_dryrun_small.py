@@ -15,6 +15,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# the child's own subprocess timeout (560 s) must fire before the test's
+pytestmark = pytest.mark.time_limit(600)
+
 
 def run_child(body: str, timeout: int = 560) -> str:
     code = textwrap.dedent("""

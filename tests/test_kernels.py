@@ -299,6 +299,7 @@ class TestMultiBlockScoreSelect:
             np.testing.assert_array_equal(p[e * seg + n:(e + 1) * seg], 0.0)
             off += n
 
+    @pytest.mark.time_limit(600)  # above the child's own 570 s timeout
     def test_sharded_topm_multi_device_subprocess(self):
         """A real 8-way client device axis reproduces the fused cohort
         (subprocess: XLA forced host devices, like the pod-mesh test)."""

@@ -23,7 +23,8 @@ Covers the acceptance criteria:
     its historical output format preserved.
 """
 
-import dataclasses
+import contextlib
+import io
 import json
 
 import numpy as np
@@ -31,17 +32,8 @@ import pytest
 
 import jax
 
-from repro.configs.base import FedConfig
-from repro.configs.registry import get_config, smoke_variant
-from repro.data import make_vision_data
-from repro.fed import (
-    AsyncConfig,
-    CheckpointHook,
-    FederatedSpec,
-    HierarchyConfig,
-    KillAtRound,
-    SimulatedPreemption,
-)
+from conftest import KILL_AT, MATRIX, ROUNDS, make_spec_factory
+from repro.fed import CheckpointHook, KillAtRound, SimulatedPreemption
 from repro.obs import (
     MetricsRegistry,
     StabilityReport,
@@ -53,50 +45,27 @@ from repro.obs import (
 )
 from repro.obs.trace import NULL_TRACER, PID_SIM, PID_WALL
 
-ROUNDS = 4
-KILL_AT = 1
-
 
 @pytest.fixture(scope="module")
-def setup():
-    from repro.models import build_model
-    model = build_model(dataclasses.replace(
-        smoke_variant(get_config("resnet18-cifar10")), d_model=8))
-    fed = FedConfig(num_clients=6, participation=0.5, rounds=ROUNDS,
-                    local_epochs=1, local_batch=8, lr=0.2, mu=0.1,
-                    dirichlet_alpha=0.1, seed=0)
-    data = make_vision_data(fed, train_per_class=24, test_per_class=8,
-                            noise=0.3)
-    return fed, data, model
-
-
-def make_spec_factory(setup, policy, topology):
-    """A ``make_spec(hooks, **over)`` callable for one matrix cell."""
-    fed, data, model = setup
-    kw = dict(selector="heterosel", steps_per_round=2)
-    if topology == "hierarchical":
-        fed = dataclasses.replace(fed, topology="hierarchical", edge_count=3)
-        kw["hier_cfg"] = HierarchyConfig(edges_per_round=2)
-    if policy == "async":
-        fed = dataclasses.replace(fed, round_policy="async")
-        kw["system"] = np.asarray([1.0, 3.0, 0.5, 2.5, 1.0, 4.0])
-        kw["async_cfg"] = AsyncConfig(deadline=1.5, over_select_frac=0.5,
-                                      jitter=0.1)
-
-    def make_spec(hooks, **over):
-        return FederatedSpec(model, fed, data, hooks=list(hooks),
-                             **{**kw, **over})
-
-    return make_spec
-
-
-@pytest.fixture(scope="module")
-def sync_run(setup, tmp_path_factory):
+def sync_run(matrix_setup, tmp_path_factory):
     """One telemetry-attached sync/flat run shared by the cheap tests."""
     td = str(tmp_path_factory.mktemp("obs") / "run0")
     hook = TelemetryHook(trace_dir=td)
-    res = make_spec_factory(setup, "sync", "flat")([hook]).build().run()
+    res = make_spec_factory(matrix_setup, "sync", "flat")(
+        [hook]).build().run()
     return res, hook, td
+
+
+@pytest.fixture(scope="module")
+def verbose_run(matrix_setup):
+    """One sync/flat run with ``verbose=True`` and no other hook, and what
+    it printed: ``(engine, result, stdout)``."""
+    eng = make_spec_factory(matrix_setup, "sync", "flat")(
+        [], verbose=True).build()
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        res = eng.run()
+    return eng, res, out.getvalue()
 
 
 # ---------------------------------------------------------------------------
@@ -167,15 +136,17 @@ class TestTraceSchema:
         _, hook, td = sync_run
         assert load_jsonl(f"{td}/trace.jsonl") == hook.recorder.events
 
-    def test_seeded_runs_trace_deterministically(self, setup, tmp_path):
-        make_spec = make_spec_factory(setup, "sync", "flat")
+    def test_seeded_runs_trace_deterministically(self, matrix_setup,
+                                                 sync_run, tmp_path):
+        make_spec = make_spec_factory(matrix_setup, "sync", "flat")
 
         def trace(d):
             hook = TelemetryHook(trace_dir=str(tmp_path / d))
             make_spec([hook]).build().run()
             return hook.recorder.events
 
-        a, b = trace("a"), trace("b")
+        # the first seeded run is the module's shared one
+        a, b = list(sync_run[1].recorder.events), trace("b")
         # the sim timeline is virtual time: bit-identical across runs
         sim = lambda evs: [e for e in evs if e["pid"] == PID_SIM]
         assert sim(a) == sim(b)
@@ -191,14 +162,11 @@ class TestTraceSchema:
 # ---------------------------------------------------------------------------
 
 
-MATRIX = [(p, t) for p in ("sync", "async") for t in ("flat", "hierarchical")]
-
-
 @pytest.mark.parametrize("policy,topology", MATRIX)
-def test_telemetry_golden_matrix(setup, policy, topology):
+def test_telemetry_golden_matrix(matrix_setup, policy, topology):
     """Attaching the full telemetry plane must not move a single bit of
     training: metrics, selection history, wall clock, uploads, params."""
-    make_spec = make_spec_factory(setup, policy, topology)
+    make_spec = make_spec_factory(matrix_setup, policy, topology)
     bare_eng = make_spec([]).build()
     assert bare_eng.tracer is NULL_TRACER and not bare_eng.tracer.enabled
     bare = bare_eng.run()
@@ -294,11 +262,12 @@ class TestStabilityReport:
 
 
 class TestResume:
-    def test_resumed_prefix_keeps_real_phase_timings(self, setup, tmp_path):
+    def test_resumed_prefix_keeps_real_phase_timings(self, matrix_setup,
+                                                     tmp_path):
         """Round snapshots persist select/execute/aggregate wall times
         (format v3); a resumed run reports the prefix's real numbers, not
         the zero backfill it used to."""
-        make_spec = make_spec_factory(setup, "sync", "flat")
+        make_spec = make_spec_factory(matrix_setup, "sync", "flat")
         ck = str(tmp_path / "ck")
         killed = make_spec([CheckpointHook(ck, every=1),
                             KillAtRound(KILL_AT)]).build()
@@ -318,11 +287,12 @@ class TestResume:
                                           np.asarray(want), err_msg=name)
             assert (got > 0.0).all(), name  # no zero backfill anywhere
 
-    def test_killed_and_resumed_telemetry_is_seamless(self, setup, tmp_path):
+    def test_killed_and_resumed_telemetry_is_seamless(self, matrix_setup,
+                                                      tmp_path):
         """TelemetryHook state (registry + sim cursor) rides the checkpoint
         and the JSONL stream appends, so kill+resume yields one gap-free
         trace and a registry covering every round exactly once."""
-        make_spec = make_spec_factory(setup, "async", "flat")
+        make_spec = make_spec_factory(matrix_setup, "async", "flat")
         td, ck = str(tmp_path / "trace"), str(tmp_path / "ck")
         with pytest.raises(SimulatedPreemption):
             make_spec([TelemetryHook(trace_dir=td),
@@ -353,10 +323,8 @@ class TestResume:
 
 
 class TestVerboseOnRegistry:
-    def test_output_format_preserved(self, setup, capsys):
-        res = make_spec_factory(setup, "sync", "flat")(
-            [], verbose=True).build().run()
-        out = capsys.readouterr().out
+    def test_output_format_preserved(self, verbose_run):
+        _, res, out = verbose_run
         acc = np.asarray(res.accuracy)
         loss = np.asarray(res.train_loss)
         assert (f"[heterosel] round   0  accuracy={acc[0]:.4f}  "
@@ -365,9 +333,10 @@ class TestVerboseOnRegistry:
         assert (f"[heterosel] round {last:3d}  accuracy={acc[last]:.4f}  "
                 f"loss={loss[last]:.4f}") in out
 
-    def test_registry_shared_with_telemetry_hook(self, setup, capsys):
+    def test_registry_shared_with_telemetry_hook(self, matrix_setup,
+                                                 capsys):
         tel = TelemetryHook()
-        eng = make_spec_factory(setup, "sync", "flat")(
+        eng = make_spec_factory(matrix_setup, "sync", "flat")(
             [tel], verbose=True).build()
         eng.run()
         verbose = next(h for h in eng.hooks
@@ -377,10 +346,8 @@ class TestVerboseOnRegistry:
         assert [r["round"] for r in tel.registry.rounds] == list(range(ROUNDS))
         assert capsys.readouterr().out.count("[heterosel] round") == 2
 
-    def test_standalone_registry_when_no_telemetry(self, setup):
-        eng = make_spec_factory(setup, "sync", "flat")(
-            [], verbose=True).build()
-        eng.run()
+    def test_standalone_registry_when_no_telemetry(self, verbose_run):
+        eng, _, _ = verbose_run
         verbose = next(h for h in eng.hooks
                        if type(h).__name__ == "VerboseHook")
         assert isinstance(verbose.registry, MetricsRegistry)

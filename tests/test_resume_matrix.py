@@ -1,18 +1,20 @@
 """Kill-at-round-t golden resume matrix (ISSUE 7 tentpole acceptance).
 
 Every ``round_policy × topology`` combination — {sync, async} × {flat,
-hierarchical} — with and without the bf16 ``compact_state`` SoA, is run
-three times via the ``preempt_harness`` fixture: uninterrupted, killed by a
-``SimulatedPreemption`` after round t (with a ``CheckpointHook`` saving
-first), and resumed from the checkpoint directory. The resumed run must
-reproduce the uninterrupted run **bitwise**: metrics, selection history,
-``wall_clock`` / ``round_staleness`` traces, ``cloud_uploads``, final
-params, and the state-layout dtypes.
+hierarchical} — is run three times via the ``preempt_harness`` fixture:
+uninterrupted, killed by a ``SimulatedPreemption`` after round t (with a
+``CheckpointHook`` saving first), and resumed from the checkpoint
+directory. The resumed run must reproduce the uninterrupted run
+**bitwise**: metrics, selection history, ``wall_clock`` /
+``round_staleness`` traces, ``cloud_uploads``, final params, and the
+state-layout dtypes. This file holds the f32-state half of the matrix;
+the ``compact_state`` half and the selector-zoo cells are in
+``test_resume_matrix_variants.py``.
 
-The async configurations are deliberately hostile: heterogeneous latency
-multipliers, a finite deadline, over-selection and log-normal jitter, so at
-the kill point the virtual clock genuinely holds in-flight completions
-(pending delta payloads, busy clients/edges) that the snapshot must carry.
+The async configurations are deliberately hostile (``make_spec_factory``
+in conftest.py), so at the kill point the virtual clock genuinely holds
+in-flight completions (pending delta payloads, busy clients/edges) that the
+snapshot must carry.
 
 Also covered here: the mid-phase kill variant, engine-kind and edge-count
 mismatch refusal, compact_state flips refused on the dtype schema,
@@ -21,133 +23,50 @@ fallback (loud, never silent).
 """
 
 import dataclasses
+import os
 
 import numpy as np
 import pytest
 
-import jax
-
-from repro.ckpt import CheckpointMismatchError, list_federated_rounds
-from repro.configs.base import FedConfig
-from repro.configs.registry import get_config, smoke_variant
-from repro.core.state import field_dtypes
-from repro.data import make_vision_data
+from conftest import (
+    KILL_AT,
+    MATRIX,
+    assert_bitwise_resume,
+    make_spec_factory,
+    shared_run,
+)
+from repro.ckpt import (
+    CheckpointMismatchError,
+    list_federated_rounds,
+    read_federated_meta,
+)
 from repro.fed import (
-    AsyncConfig,
     CheckpointHook,
     FederatedSpec,
-    HierarchyConfig,
     KillAtRound,
     SimulatedPreemption,
 )
 
-ROUNDS = 4
-KILL_AT = 1  # snapshot on disk covers rounds 0..1 → resume from round 2
 
-
-@pytest.fixture(scope="module")
-def setup():
-    from repro.models import build_model
-    model = build_model(dataclasses.replace(
-        smoke_variant(get_config("resnet18-cifar10")), d_model=8))
-    fed = FedConfig(num_clients=6, participation=0.5, rounds=ROUNDS,
-                    local_epochs=1, local_batch=8, lr=0.2, mu=0.1,
-                    dirichlet_alpha=0.1, seed=0)
-    data = make_vision_data(fed, train_per_class=24, test_per_class=8,
-                            noise=0.3)
-    return fed, data, model
-
-
-def make_spec_factory(setup, policy, topology, compact,
-                      selector="heterosel"):
-    """A ``make_spec(hooks)`` callable for one matrix cell."""
-    fed, data, model = setup
-    kw = dict(selector=selector, steps_per_round=2, compact_state=compact)
-    if topology == "hierarchical":
-        fed = dataclasses.replace(fed, topology="hierarchical", edge_count=3)
-        kw["hier_cfg"] = HierarchyConfig(edges_per_round=2)
-    if policy == "async":
-        fed = dataclasses.replace(fed, round_policy="async")
-        mult = np.asarray([1.0, 3.0, 0.5, 2.5, 1.0, 4.0])
-        kw["system"] = mult
-        kw["async_cfg"] = AsyncConfig(deadline=1.5, over_select_frac=0.5,
-                                      jitter=0.1)
-
-    def make_spec(hooks):
-        return FederatedSpec(model, fed, data, hooks=list(hooks), **kw)
-
-    return make_spec
-
-
-def assert_bitwise_resume(full, resumed, engine, *, compact):
-    assert engine.start_round == KILL_AT + 1
-    np.testing.assert_array_equal(resumed.selected_history,
-                                  full.selected_history)
-    # float series compare as exact bit patterns, not tolerances
-    np.testing.assert_array_equal(np.asarray(resumed.accuracy),
-                                  np.asarray(full.accuracy))
-    np.testing.assert_array_equal(np.asarray(resumed.train_loss),
-                                  np.asarray(full.train_loss))
-    for name in ("wall_clock", "round_staleness", "cloud_uploads"):
-        a, b = getattr(full, name), getattr(resumed, name)
-        assert (a is None) == (b is None), name
-        if a is not None:
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
-    for (ka, a), (kb, b) in zip(
-            jax.tree_util.tree_leaves_with_path(full.params),
-            jax.tree_util.tree_leaves_with_path(resumed.params)):
-        assert ka == kb
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.dtype == b.dtype
-        np.testing.assert_array_equal(a.view(np.uint8), b.view(np.uint8),
-                                      err_msg=str(ka))
-    # the checkpoint must hand back the SoA layout it was given
-    layout = field_dtypes(engine.state)
-    assert layout["last_selected"] == "int32"
-    assert layout["loss_prev"] == ("bfloat16" if compact else "float32")
-
-
-MATRIX = [(p, t) for p in ("sync", "async") for t in ("flat", "hierarchical")]
-
-
+# compact=True is in test_resume_matrix_variants.py; the ids stay the ones
+# that CI pins
 @pytest.mark.parametrize("policy,topology", MATRIX)
-@pytest.mark.parametrize("compact", [False, True],
-                         ids=["f32state", "compact"])
-def test_kill_at_round_t_resumes_bitwise(setup, preempt_harness, policy,
-                                         topology, compact):
-    make_spec = make_spec_factory(setup, policy, topology, compact)
-    full, resumed, engine = preempt_harness(make_spec, KILL_AT)
+@pytest.mark.parametrize("compact", [False], ids=["f32state"])
+def test_kill_at_round_t_resumes_bitwise(matrix_setup, preempt_harness,
+                                         policy, topology, compact):
+    make_spec = make_spec_factory(matrix_setup, policy, topology, compact)
+    full, resumed, engine = preempt_harness(make_spec([]), KILL_AT)
     assert_bitwise_resume(full, resumed, engine, compact=compact)
 
 
-# The zoo selectors added by ISSUE 10 must honor the same golden-resume
-# contract. 'adaptive' × hierarchical is the hard cell: the snapshot must
-# also carry the edge-budget controller's EMA utilities and the retuned
-# per-edge budgets (HierarchicalEngine.extra_state).
-NEW_SELECTOR_CELLS = [
-    ("filtered", "sync", "flat"),
-    ("adaptive", "async", "flat"),
-    ("adaptive", "sync", "hierarchical"),
-]
-
-
-@pytest.mark.parametrize("selector,policy,topology", NEW_SELECTOR_CELLS)
-def test_kill_at_round_t_resumes_bitwise_new_selectors(
-        setup, preempt_harness, selector, policy, topology):
-    make_spec = make_spec_factory(setup, policy, topology, False,
-                                  selector=selector)
-    full, resumed, engine = preempt_harness(make_spec, KILL_AT)
-    assert_bitwise_resume(full, resumed, engine, compact=False)
-
-
-def test_async_snapshot_carries_in_flight_state(setup, preempt_harness):
+def test_async_snapshot_carries_in_flight_state(matrix_setup,
+                                                preempt_harness):
     """The hostile async profile must actually exercise the clock payload
     path — otherwise the matrix would pass with an empty event queue."""
-    make_spec = make_spec_factory(setup, "async", "flat", False)
-    full, resumed, engine = preempt_harness(make_spec, KILL_AT)
+    make_spec = make_spec_factory(matrix_setup, "async", "flat", False)
+    full, resumed, engine = preempt_harness(make_spec([]), KILL_AT)
     meta_rounds = list_federated_rounds(engine.hooks[-1].path)
     assert meta_rounds  # checkpoints were written
-    from repro.ckpt import read_federated_meta
     metas = [read_federated_meta(engine.hooks[-1].path, r)
              for r in meta_rounds]
     assert any(m["extra"]["clock"]["events"] for m in metas), (
@@ -156,38 +75,39 @@ def test_async_snapshot_carries_in_flight_state(setup, preempt_harness):
     assert_bitwise_resume(full, resumed, engine, compact=False)
 
 
-def test_mid_phase_kill_resumes_bitwise(setup, preempt_harness):
+def test_mid_phase_kill_resumes_bitwise(matrix_setup, preempt_harness):
     """phase='round_start' dies at the start of round t+1 — after the
     round-t snapshot but inside the next round's hook sequence."""
-    make_spec = make_spec_factory(setup, "async", "hierarchical", False)
-    full, resumed, engine = preempt_harness(make_spec, KILL_AT,
+    make_spec = make_spec_factory(matrix_setup, "async", "hierarchical",
+                                  False)
+    full, resumed, engine = preempt_harness(make_spec([]), KILL_AT,
                                             phase="round_start")
     assert_bitwise_resume(full, resumed, engine, compact=False)
 
 
 class TestMismatchRefusal:
-    def test_engine_kind_mismatch_is_loud(self, setup, tmp_path):
-        make_sync = make_spec_factory(setup, "sync", "flat", False)
+    def test_engine_kind_mismatch_is_loud(self, matrix_setup, tmp_path):
+        make_sync = make_spec_factory(matrix_setup, "sync", "flat", False)
         ckdir = str(tmp_path / "kind")
         with pytest.raises(SimulatedPreemption):
             make_sync([CheckpointHook(ckdir, every=1),
                        KillAtRound(KILL_AT)]).build().run()
-        make_async = make_spec_factory(setup, "async", "flat", False)
+        make_async = make_spec_factory(matrix_setup, "async", "flat", False)
         with pytest.raises(CheckpointMismatchError, match="sync/flat"):
             make_async([CheckpointHook(ckdir, every=1)]).build().run()
 
-    def test_compact_state_flip_is_loud(self, setup, tmp_path):
-        make_compact = make_spec_factory(setup, "sync", "flat", True)
+    def test_compact_state_flip_is_loud(self, matrix_setup, tmp_path):
+        make_compact = make_spec_factory(matrix_setup, "sync", "flat", True)
         ckdir = str(tmp_path / "compact")
         with pytest.raises(SimulatedPreemption):
             make_compact([CheckpointHook(ckdir, every=1),
                           KillAtRound(KILL_AT)]).build().run()
-        make_f32 = make_spec_factory(setup, "sync", "flat", False)
+        make_f32 = make_spec_factory(matrix_setup, "sync", "flat", False)
         with pytest.raises(CheckpointMismatchError, match="dtype"):
             make_f32([CheckpointHook(ckdir, every=1)]).build().run()
 
-    def test_edge_count_mismatch_is_loud(self, setup, tmp_path):
-        fed, data, model = setup
+    def test_edge_count_mismatch_is_loud(self, matrix_setup, tmp_path):
+        fed, data, model = matrix_setup
         ckdir = str(tmp_path / "edges")
         hfed = dataclasses.replace(fed, topology="hierarchical", edge_count=3)
         with pytest.raises(SimulatedPreemption):
@@ -205,9 +125,9 @@ class TestMismatchRefusal:
 
 class TestRetentionAndFallback:
     def test_keep_last_retains_exactly_n_and_resumes_latest(
-            self, setup, tmp_path):
-        make_spec = make_spec_factory(setup, "sync", "flat", False)
-        full = make_spec([]).build().run()
+            self, matrix_setup, tmp_path):
+        make_spec = make_spec_factory(matrix_setup, "sync", "flat", False)
+        full = shared_run(make_spec([]))
         ckdir = str(tmp_path / "keep")
         with pytest.raises(SimulatedPreemption):
             make_spec([CheckpointHook(ckdir, every=1, keep_last=2),
@@ -222,16 +142,15 @@ class TestRetentionAndFallback:
         np.testing.assert_array_equal(np.asarray(resumed.accuracy),
                                       np.asarray(full.accuracy))
 
-    def test_corrupt_latest_falls_back_loudly(self, setup, tmp_path):
-        make_spec = make_spec_factory(setup, "sync", "flat", False)
-        full = make_spec([]).build().run()
+    def test_corrupt_latest_falls_back_loudly(self, matrix_setup, tmp_path):
+        make_spec = make_spec_factory(matrix_setup, "sync", "flat", False)
+        full = shared_run(make_spec([]))
         ckdir = str(tmp_path / "corrupt")
         with pytest.raises(SimulatedPreemption):
             make_spec([CheckpointHook(ckdir, every=1),
                        KillAtRound(2)]).build().run()
         assert list_federated_rounds(ckdir) == [1, 2, 3]
         # truncate the newest npz mid-write, like a real preemption would
-        import os
         npz = os.path.join(ckdir, "fedround_00000003.npz")
         with open(npz, "r+b") as f:
             f.truncate(100)
@@ -244,13 +163,12 @@ class TestRetentionAndFallback:
         np.testing.assert_array_equal(np.asarray(resumed.accuracy),
                                       np.asarray(full.accuracy))
 
-    def test_all_snapshots_corrupt_raises(self, setup, tmp_path):
-        make_spec = make_spec_factory(setup, "sync", "flat", False)
+    def test_all_snapshots_corrupt_raises(self, matrix_setup, tmp_path):
+        make_spec = make_spec_factory(matrix_setup, "sync", "flat", False)
         ckdir = str(tmp_path / "allbad")
         with pytest.raises(SimulatedPreemption):
             make_spec([CheckpointHook(ckdir, every=1),
                        KillAtRound(1)]).build().run()
-        import os
         for r in list_federated_rounds(ckdir):
             with open(os.path.join(ckdir, f"fedround_{r:08d}.npz"),
                       "r+b") as f:
