@@ -36,14 +36,15 @@ def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
 
     da = dt * a
     cum = jnp.cumsum(da)                          # (cl,)
-    # decay[i, j] = exp(cum_i − cum_j) for j ≤ i else 0
-    decay = jnp.exp(cum[:, None] - cum[None, :])
+    # decay[i, j] = exp(cum_i − cum_j) for j ≤ i else 0, masked in log space
+    # before the exp: above the diagonal the exponent is ≥ 0 and overflows
+    # to inf once the chunk's Σ dt·|A| passes ≈ 88 (inf · 0 would be NaN).
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    causal = (ii >= jj).astype(jnp.float32)
+    decay = jnp.exp(jnp.where(ii >= jj, cum[:, None] - cum[None, :], -jnp.inf))
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    w = scores * decay * causal * dt[None, :]     # (cl, cl)
+    w = scores * decay * dt[None, :]              # (cl, cl)
     y_ref[0, 0] = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     ).astype(y_ref.dtype)

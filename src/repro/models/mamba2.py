@@ -85,56 +85,71 @@ def _ssd_chunked(
     chunk: int,
     h0: jax.Array | None = None,  # (B,nh,hp,N) initial state
 ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked SSD scan. Returns (y (B,S,nh,hp), final_state (B,nh,hp,N))."""
-    bsz, s, nh, hp = x.shape
-    n = b_in.shape[-1]
-    nc = -(-s // chunk)
-    pad = nc * chunk - s
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        b_in = jnp.pad(b_in, ((0, 0), (0, pad), (0, 0)))
-        c_in = jnp.pad(c_in, ((0, 0), (0, pad), (0, 0)))
-    xc = x.reshape(bsz, nc, chunk, nh, hp)
-    dtc = dt.reshape(bsz, nc, chunk, nh)
-    bc = b_in.reshape(bsz, nc, chunk, n)
-    cc = c_in.reshape(bsz, nc, chunk, n)
+    """Chunked SSD scan. Returns (y (B,S,nh,hp), final_state (B,nh,hp,N)).
 
-    da = dtc * a_neg  # (B,nc,cl,nh) — log-decay increments (≤0)
-    cum = jnp.cumsum(da, axis=2)  # inclusive cumulative log decay
+    Its ops carry the name scope ``mamba2.ssd`` (sub-scopes ``intra``,
+    ``states``, ``scan``, ``inter``), so a device trace can tell the scan's
+    time from the rest of the step; see docs/observability.md.
+    """
+    with jax.named_scope("mamba2.ssd"):
+        bsz, s, nh, hp = x.shape
+        n = b_in.shape[-1]
+        nc = -(-s // chunk)
+        pad = nc * chunk - s
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+            b_in = jnp.pad(b_in, ((0, 0), (0, pad), (0, 0)))
+            c_in = jnp.pad(c_in, ((0, 0), (0, pad), (0, 0)))
+        xc = x.reshape(bsz, nc, chunk, nh, hp)
+        dtc = dt.reshape(bsz, nc, chunk, nh)
+        bc = b_in.reshape(bsz, nc, chunk, n)
+        cc = c_in.reshape(bsz, nc, chunk, n)
 
-    # Intra-chunk (attention-like, causal with decay weights).
-    #   W[b,c,i,j,h] = exp(cum_i − cum_j) · dt_j · (C_i · B_j)   for j ≤ i
-    scores = jnp.einsum("bcin,bcjn->bcij", cc, bc)  # (B,nc,cl,cl)
-    decay = jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :])  # (B,nc,i,j,h)
-    idx = jnp.arange(chunk)
-    causal = (idx[:, None] >= idx[None, :]).astype(jnp.float32)
-    w = scores[..., None] * decay * causal[None, None, :, :, None] * dtc[:, :, None, :, :]
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", w, xc)
+        da = dtc * a_neg  # (B,nc,cl,nh) — log-decay increments (≤0)
+        cum = jnp.cumsum(da, axis=2)  # inclusive cumulative log decay
 
-    # Per-chunk terminal states:  state[b,c,h,p,n] = Σ_j e^{cum_last−cum_j}·dt_j·x_j⊗B_j
-    decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # (B,nc,cl,nh)
-    wstate = decay_to_end * dtc
-    states = jnp.einsum("bcjh,bcjhp,bcjn->bchpn", wstate, xc, bc)
+        # Intra-chunk (attention-like, causal with decay weights).
+        #   W[b,c,i,j,h] = exp(cum_i − cum_j) · dt_j · (C_i · B_j)   for j ≤ i
+        # The causal mask is applied to the log-decay (a masked segment sum)
+        # before the exp: above the diagonal cum_i − cum_j ≥ 0 and grows with
+        # the chunk's Σ dt·|A|, which past ≈ 88 overflows float32 to inf, and
+        # inf · 0 would be NaN in the output and the gradient.
+        with jax.named_scope("intra"):
+            scores = jnp.einsum("bcin,bcjn->bcij", cc, bc)  # (B,nc,cl,cl)
+            idx = jnp.arange(chunk)
+            causal = (idx[:, None] >= idx[None, :])[None, None, :, :, None]
+            segsum = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,i,j,h)
+            decay = jnp.exp(jnp.where(causal, segsum, -jnp.inf))
+            w = scores[..., None] * decay * dtc[:, :, None, :, :]
+            y_intra = jnp.einsum("bcijh,bcjhp->bcihp", w, xc)
 
-    # Cross-chunk recurrence.
-    chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B,nc,nh)
-    h_init = jnp.zeros((bsz, nh, hp, n), jnp.float32) if h0 is None else h0
+        # Per-chunk terminal states:  state[b,c,h,p,n] = Σ_j e^{cum_last−cum_j}·dt_j·x_j⊗B_j
+        with jax.named_scope("states"):
+            decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # (B,nc,cl,nh)
+            wstate = decay_to_end * dtc
+            states = jnp.einsum("bcjh,bcjhp,bcjn->bchpn", wstate, xc, bc)
 
-    def step(h, inp):
-        st, dec = inp  # (B,nh,hp,n), (B,nh)
-        h_out = h  # state *entering* this chunk
-        h_new = dec[:, :, None, None] * h + st
-        return h_new, h_out
+        # Cross-chunk recurrence.
+        with jax.named_scope("scan"):
+            chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B,nc,nh)
+            h_init = jnp.zeros((bsz, nh, hp, n), jnp.float32) if h0 is None else h0
 
-    hs_in = (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2))
-    h_final, h_enter = jax.lax.scan(step, h_init, hs_in)
-    h_enter = h_enter.transpose(1, 0, 2, 3, 4)  # (B,nc,nh,hp,n)
+            def step(h, inp):
+                st, dec = inp  # (B,nh,hp,n), (B,nh)
+                h_out = h  # state *entering* this chunk
+                h_new = dec[:, :, None, None] * h + st
+                return h_new, h_out
 
-    # Inter-chunk contribution: C_i · (e^{cum_i} · H_enter)
-    y_inter = jnp.einsum("bcin,bcih,bchpn->bcihp", cc, jnp.exp(cum), h_enter)
-    y = (y_intra + y_inter).reshape(bsz, nc * chunk, nh, hp)
-    return y[:, :s], h_final
+            hs_in = (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2))
+            h_final, h_enter = jax.lax.scan(step, h_init, hs_in)
+            h_enter = h_enter.transpose(1, 0, 2, 3, 4)  # (B,nc,nh,hp,n)
+
+        # Inter-chunk contribution: C_i · (e^{cum_i} · H_enter)
+        with jax.named_scope("inter"):
+            y_inter = jnp.einsum("bcin,bcih,bchpn->bcihp", cc, jnp.exp(cum), h_enter)
+            y = (y_intra + y_inter).reshape(bsz, nc * chunk, nh, hp)
+        return y[:, :s], h_final
 
 
 def block_forward(

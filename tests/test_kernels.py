@@ -85,15 +85,18 @@ class TestFlashAttention:
 
 
 class TestSSDScan:
-    @pytest.mark.parametrize("s,nh,hp,n,chunk", [
-        (64, 2, 16, 8, 16),
-        (96, 3, 32, 16, 32),   # padded last chunk
-        (128, 1, 64, 32, 128), # single chunk
+    @pytest.mark.parametrize("s,nh,hp,n,chunk,dt_shift", [
+        pytest.param(64, 2, 16, 8, 16, 0.0, id="64-2-16-8-16"),
+        pytest.param(96, 3, 32, 16, 32, 0.0, id="96-3-32-16-32"),  # padded last chunk
+        pytest.param(128, 1, 64, 32, 128, 0.0, id="128-1-64-32-128"),  # single chunk
+        # dt ≈ 8: a chunk's Σ dt·|A| ≈ 128 > 88, so exp(cum_i − cum_j) above
+        # the diagonal overflows float32 unless it is masked before the exp.
+        pytest.param(64, 2, 16, 8, 16, 8.0, id="overflow"),
     ])
-    def test_against_exact_recurrence(self, s, nh, hp, n, chunk):
+    def test_against_exact_recurrence(self, s, nh, hp, n, chunk, dt_shift):
         k1, k2, k3, k4, k5 = jax.random.split(KEY, 5)
         x = jax.random.normal(k1, (2, s, nh, hp))
-        dt = jax.nn.softplus(jax.random.normal(k2, (2, s, nh)))
+        dt = jax.nn.softplus(jax.random.normal(k2, (2, s, nh)) + dt_shift)
         a_neg = -jnp.exp(jax.random.normal(k3, (nh,)) * 0.3)
         b_in = jax.random.normal(k4, (2, s, n)) * 0.5
         c_in = jax.random.normal(k5, (2, s, n)) * 0.5
