@@ -133,7 +133,8 @@ def test_phase_touches_nothing_it_is_given(profiler):
 
 class LinearClassifier:
     """Softmax regression on flattened images: a model the engine trains and
-    evaluates (eagerly, as ``default_eval`` does) in well under a second."""
+    evaluates (one compiled program, as ``default_eval`` runs every model) in
+    well under a second."""
 
     cfg = types.SimpleNamespace(family="resnet")
 
