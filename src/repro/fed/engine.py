@@ -1,7 +1,7 @@
 """Composable federated round engine (paper Algorithm 1 as a plugin surface).
 
-``FederatedEngine`` owns the Algorithm-1 skeleton — score → select → local
-train → aggregate → metadata update → eval — and delegates each stage to a
+``FederatedEngine`` owns the one Algorithm-1 round — select → execute →
+aggregate → state update → eval — and delegates each stage to a
 protocol-typed plugin, so new execution engines, wire codecs, aggregation
 rules and cross-cutting behaviours land without touching the loop:
 
@@ -21,6 +21,12 @@ rules and cross-cutting behaviours land without touching the loop:
     collection (``MetricsHook``), verbose logging (``VerboseHook``),
     Lemma-A.4 μ retuning (``AdaptiveMuHook``), and mid-run checkpoint/resume
     (``CheckpointHook``, backed by ``repro.ckpt``).
+
+The regimes are data the engine holds, not subclasses with rounds of their
+own: a topology (``FlatTopology`` | ``fed.hierarchy.HierarchicalTopology``)
+says who is selected and what one dispatch produces, a round policy
+(``SyncPolicy`` | ``fed.async_engine.AsyncPolicy``) when updates arrive and
+how they are reduced.
 
 Configuration is one ``FederatedSpec`` builder: registry-backed
 ``executor=`` / ``aggregator=`` / ``hooks=`` names (or instances), replacing
@@ -62,7 +68,11 @@ from repro import ckpt as repro_ckpt
 from repro.configs.base import FedConfig
 from repro.core.adaptive import AdaptiveMu
 from repro.core.scoring import HeteRoScoreConfig
-from repro.core.selection import SelectorConfig, make_selector
+from repro.core.selection import (
+    SelectorConfig,
+    make_async_selector,
+    make_selector,
+)
 from repro.core.state import (
     ClientState,
     init_client_state,
@@ -274,6 +284,26 @@ class CohortUpdates:
     staleness: Optional[np.ndarray] = None  # async: (M,) version lag
 
 
+@dataclasses.dataclass
+class Update:
+    """What one dispatched unit brings back to the server: the flat cohort
+    (or, under the async policy, each of its clients) or one edge's cohort.
+
+    ``unit`` is the id the virtual clock and the in-flight mask know it by
+    (a client id flat, an edge id hierarchical). ``losses``/``sqnorms`` are
+    the observations of ``clients``, in that order; a batched flat cohort
+    keeps them on the device."""
+
+    unit: int
+    clients: np.ndarray
+    losses: Any
+    sqnorms: Any
+    weight: float = 1.0              # server weight: summed cohort weights
+    params: Any = None               # the params it proposes
+    delta: Any = None                # async: f32 params − dispatch anchor
+    cohort: Optional[CohortUpdates] = None  # flat: the executor's output
+
+
 @runtime_checkable
 class ClientExecutor(Protocol):
     """How the selected cohort trains for one round.
@@ -319,15 +349,20 @@ class Aggregator:
     def set_state(self, state: Any) -> None:
         pass
 
-    def _mean(self, cohort: CohortUpdates) -> Any:
-        if cohort.avg_params is not None:
-            return cohort.avg_params
-        if cohort.param_list is None:
-            raise ValueError("cohort carries neither avg_params nor param_list")
-        if cohort.weights is not None:
-            return fed_server.fedavg_weighted(cohort.param_list,
-                                              np.asarray(cohort.weights))
-        return fed_server.fedavg(cohort.param_list)
+
+def cohort_mean(cohort: CohortUpdates) -> Any:
+    """The cohort's (weighted) parameter mean: the batched path's fused mean
+    as it came back, else FedAvg over the sequential path's list. Every
+    aggregator reduces from it, and the hierarchical edge tier takes it as
+    the edge aggregate."""
+    if cohort.avg_params is not None:
+        return cohort.avg_params
+    if cohort.param_list is None:
+        raise ValueError("cohort carries neither avg_params nor param_list")
+    if cohort.weights is not None:
+        return fed_server.fedavg_weighted(cohort.param_list,
+                                          np.asarray(cohort.weights))
+    return fed_server.fedavg(cohort.param_list)
 
 
 class RoundHook:
@@ -645,7 +680,7 @@ class FedAvg(Aggregator):
     name = "fedavg"
 
     def reduce(self, global_params, cohort):
-        return self._mean(cohort)
+        return cohort_mean(cohort)
 
 
 class WeightedFedAvg(Aggregator):
@@ -675,7 +710,7 @@ class WeightedFedAvg(Aggregator):
         return jnp.asarray(self._sizes[selected])
 
     def reduce(self, global_params, cohort):
-        return self._mean(cohort)
+        return cohort_mean(cohort)
 
 
 class FedAvgM(Aggregator):
@@ -687,7 +722,7 @@ class FedAvgM(Aggregator):
         self.momentum = fed_server.ServerMomentum(beta=beta)
 
     def reduce(self, global_params, cohort):
-        return self.momentum.apply(global_params, self._mean(cohort))
+        return self.momentum.apply(global_params, cohort_mean(cohort))
 
     def get_state(self):
         return self.momentum.velocity
@@ -839,10 +874,11 @@ class CheckpointHook(RoundHook):
     including the int32 ``NEVER`` sentinel), the jax PRNG key, the host
     numpy RNG state, aggregator state (momentum velocity, FedBuff buffer),
     sibling-hook state (``RoundHook.state_dict`` — e.g. the adaptive-μ
-    controller's EMAs), the metric series, and whatever the running engine
-    declares via its ``extra_state`` protocol — the async virtual clock with
-    its pending in-flight updates and staleness counters, the hierarchical
-    cloud-upload series and in-flight edge cohorts. A run killed at round t
+    controller's EMAs), the metric series, and whatever the engine's
+    topology and round policy declare via ``RoundPart.extra_state`` — the
+    async virtual clock with its pending in-flight updates and staleness
+    counters, the hierarchical cloud-upload series and in-flight edge
+    cohorts. A run killed at round t
     and resumed therefore reproduces the uninterrupted run bitwise for every
     ``round_policy × topology`` combination (tests/test_resume_matrix.py).
 
@@ -1013,7 +1049,7 @@ class FederatedSpec:
     mesh_axes: Optional[MeshAxes] = None
     verbose: bool = False
     # Round management: None defers to fed.round_policy ('sync' | 'async').
-    # 'async' builds an AsyncFederatedEngine (fed.async_engine): event-driven
+    # 'async' runs the round under fed.async_engine.AsyncPolicy: event-driven
     # virtual clock, deadline-closed rounds with over-selection, buffered
     # staleness-aware aggregation. ``system`` supplies per-client round-time
     # multipliers (a fed.availability.SystemProfile or a (K,) array);
@@ -1022,11 +1058,11 @@ class FederatedSpec:
     async_cfg: Optional[Any] = None      # fed.async_engine.AsyncConfig
     system: Optional[Any] = None         # SystemProfile | (K,) multipliers
     # Federation topology: None defers to fed.topology ('flat' |
-    # 'hierarchical'). 'hierarchical' builds a HierarchicalEngine
-    # (fed.hierarchy): clients partitioned into FedConfig.edge_count edge
-    # groups, HeteRo-Select twice per round (per-edge budgets + cross-edge
-    # pooled scores), two-stage aggregation; composes with either round
-    # policy. ``hier_cfg`` holds the partition/outer-budget knobs.
+    # 'hierarchical'). 'hierarchical' runs the round over
+    # fed.hierarchy.HierarchicalTopology: clients partitioned into
+    # FedConfig.edge_count edge groups, HeteRo-Select twice per round
+    # (per-edge budgets + cross-edge pooled scores), two-stage aggregation;
+    # composes with either round policy. ``hier_cfg`` holds the partition/outer-budget knobs.
     topology: Optional[str] = None
     hier_cfg: Optional[Any] = None       # fed.hierarchy.HierarchyConfig
     # Keep the (K,) selection metadata in bf16 (core.state.to_bf16) — halves
@@ -1053,15 +1089,16 @@ class FederatedSpec:
         return self.topology or getattr(self.fed, "topology", "flat")
 
     def build(self) -> "FederatedEngine":
+        """The engine for this spec. Every regime runs the one round of
+        ``FederatedEngine``; the type says which it is: ``FederatedEngine``
+        (flat sync), ``AsyncFederatedEngine`` (flat async) or
+        ``HierarchicalEngine`` (either policy)."""
         policy = self.resolved_round_policy
         if policy not in ("sync", "async"):
             raise ValueError(
                 f"round_policy must be 'sync' or 'async', got {policy!r}")
         topo = self.resolved_topology
         if topo == "hierarchical":
-            # The hierarchical engine owns both round policies itself (the
-            # unit of cloud arrival is an edge aggregate, not a client
-            # update, so flat-async cannot be stacked underneath).
             from repro.fed.hierarchy import HierarchicalEngine
 
             return HierarchicalEngine(self)
@@ -1083,33 +1120,7 @@ class FederatedSpec:
             from repro.fed.async_engine import AsyncFederatedEngine
 
             return AsyncFederatedEngine(self)
-        if self.async_cfg is not None or self.system is not None:
-            # The sync engine has no clock: silently modeling a homogeneous
-            # instant fleet while the config says otherwise is how wrong
-            # conclusions get drawn. Loud, like every other bad combination.
-            raise ValueError(
-                "async_cfg/system are only consumed by round_policy='async'; "
-                "the sync engine has no wall clock to apply them to")
         return FederatedEngine(self)
-
-
-def _codec_schedule_conflict(spec: FederatedSpec, name: str) -> Optional[str]:
-    """Why ``spec.compression`` cannot ride the named schedule, or None."""
-    if spec.compression is None or name != "batched":
-        return None
-    if spec.compression == "topk":
-        return "compression='topk' keeps per-client host-side residuals"
-    if spec.compression == "int8":
-        if spec.fed.client_chunk:
-            return ("compression='int8' needs the full (M, ...) client stack, "
-                    "which chunked execution (client_chunk > 0) never "
-                    "materializes")
-        if spec.mesh is not None and spec.mesh_axes is not None \
-                and spec.mesh_axes.pod is not None \
-                and axis_size(spec.mesh, spec.mesh_axes.pod) > 1:
-            return ("compression='int8' over a pod-sharded batched cohort "
-                    "is not supported yet")
-    return None
 
 
 def _resolve_executor(spec: FederatedSpec) -> ClientExecutor:
@@ -1120,20 +1131,23 @@ def _resolve_executor(spec: FederatedSpec) -> ClientExecutor:
         if name not in EXECUTORS:
             raise ValueError(
                 f"client_execution must be one of {sorted(EXECUTORS)}, got {name!r}")
-        conflict = _codec_schedule_conflict(spec, name)
-        if conflict and not explicit:
-            # The schedule was only the config default — downgrade loudly
-            # rather than refusing a run nobody mis-configured on purpose.
-            warnings.warn(
-                f"{conflict}; falling back to the sequential executor (pass "
-                "client_execution='sequential' to silence, or 'batched' to "
-                "make this an error)", stacklevel=3)
-            name = "sequential"
         ex = EXECUTORS[name](spec)
-    if spec.compression is not None:
+    if spec.compression is None:
+        return ex
+    try:
         # Explicitly-requested incompatible pairs fail in here, loudly.
-        ex = CompressedExecutor(ex, spec.compression, spec.topk_frac)
-    return ex
+        return CompressedExecutor(ex, spec.compression, spec.topk_frac)
+    except ExecutorCompatError as conflict:
+        if explicit:
+            raise
+        # The schedule was only the config default — downgrade loudly
+        # rather than refusing a run nobody mis-configured on purpose.
+        warnings.warn(
+            f"{conflict} Falling back to the sequential executor (pass "
+            "client_execution='sequential' to silence, or 'batched' to "
+            "make this an error)", stacklevel=3)
+    return CompressedExecutor(EXECUTORS["sequential"](spec), spec.compression,
+                              spec.topk_frac)
 
 
 def _resolve_aggregator(spec: FederatedSpec) -> Aggregator:
@@ -1170,14 +1184,220 @@ def _resolve_hooks(spec: FederatedSpec) -> List[RoundHook]:
     return hooks
 
 
-class FederatedEngine:
-    """Algorithm-1 skeleton over pluggable executor / aggregator / hooks.
+class RoundPart:
+    """What a topology or a round policy adds to a run: state set up at run
+    start, extras for ``FLResult``, and ``(trees, arrays, meta)`` in the
+    round snapshot. Names share one namespace with the base snapshot and
+    the other part; the meta dicts merge under the snapshot's ``"extra"``
+    key. ``extra_likes`` sees the full meta before arrays load, so restore
+    templates can depend on it (one delta tree per in-flight completion)."""
 
-    One ``run()`` = ``fed.rounds`` rounds of: split key → select cohort →
-    ``executor.run_round`` → ``aggregator.reduce`` → fold observations into
-    ``ClientState`` → eval → hooks. The engine owns only the skeleton and
-    the resumable state (params, client state, RNGs, byte counters); every
-    behaviour beyond that is a plugin."""
+    def start(self) -> None:
+        pass
+
+    def extra_state(self) -> Tuple[Dict[str, Any], Dict[str, np.ndarray],
+                                   Dict[str, Any]]:
+        return {}, {}, {}
+
+    def extra_likes(self, meta: Dict[str, Any]) -> Dict[str, Any]:
+        return {}
+
+    def load_extra_state(self, trees: Dict[str, Any],
+                         arrays: Dict[str, np.ndarray],
+                         meta: Dict[str, Any]) -> None:
+        pass
+
+    def contribute(self, extras: Dict[str, Any]) -> None:
+        pass
+
+
+class SyncPolicy(RoundPart):
+    """Round policy 'sync': every update reaches the server in the round that
+    dispatched it and is reduced at once (``topology.reduce``). Rounds cost
+    "1" on the time axis, and nothing is ever in flight."""
+
+    name = "sync"
+    clock = None
+    in_flight = None
+
+    def __init__(self, eng: "FederatedEngine"):
+        self.eng = eng
+
+    def over_select(self, m: int, cap: int) -> int:
+        return m
+
+    def dispatch(self, t: int, out: List[Update]) -> None:
+        pass
+
+    def reduce(self, ctx: RoundContext, t: int, out: List[Update],
+               span_args: Dict[str, Any]) -> List[Update]:
+        eng = self.eng
+        eng.params = eng.topology.reduce(eng.params, out)
+        ctx.sim_time = float(t + 1)
+        return out
+
+
+# Staleness reported for never-contacted clients (clipped by Eq 7's T_max).
+NEVER_STALE = 1.0e6
+
+
+class FlatTopology(RoundPart):
+    """Topology 'flat': every client reports straight to the server. One
+    jitted selector over the K clients and one executor call per round; the
+    dispatch is one cohort, reduced by the aggregator. Under the async
+    policy (``fed.async_engine`` step 1) each client of the cohort is its
+    own completion on the clock."""
+
+    name = "flat"
+    in_flight_key = "in_flight"
+
+    def __init__(self, eng: "FederatedEngine"):
+        self.eng = eng
+        spec, fed = eng.spec, eng.spec.fed
+        self.num_units = spec.data.num_clients
+        score_cfg = spec.score_cfg or HeteRoScoreConfig()
+        sel_cfg = spec.sel_cfg or SelectorConfig(num_selected=fed.num_selected)
+        self.m = eng.policy.over_select(fed.num_selected, self.num_units)
+        self.clocked = eng.policy.name == "async"
+        if self.clocked:
+            # Oort's system-utility term: preferred/actual round duration.
+            latency = eng.policy.latency
+            speeds = jnp.asarray(
+                latency.reference_time() / (latency.base * latency.multipliers),
+                jnp.float32)
+            select = make_async_selector(
+                eng.selector_name, dataclasses.replace(sel_cfg, num_selected=self.m),
+                score_cfg, speeds=speeds)
+            masked = fed_avail.mask_async_selector
+            # Each client's update is held back on its own, so the batched
+            # executor must return the (M, ...) client stack.
+            inner = getattr(eng.executor, "inner", eng.executor)
+            if getattr(inner, "kind", None) == "batched":
+                if fed.client_chunk:
+                    raise ExecutorCompatError(
+                        "async rounds need every client's update separately, "
+                        "but chunked batched execution (FedConfig.client_chunk "
+                        "> 0) never materializes the (M, ...) client stack; "
+                        "set client_chunk=0 or use the sequential executor")
+                if isinstance(inner, BatchedExecutor):
+                    inner.keep_client_params = True
+        else:
+            select = make_selector(eng.selector_name, sel_cfg, score_cfg)
+            masked = fed_avail.mask_selector
+        if spec.availability is not None:
+            select = masked(select, jnp.asarray(spec.availability),
+                            num_selected=self.m)
+        self._select = jax.jit(select)
+
+    def start(self) -> None:
+        if self.clocked:
+            # Virtual dispatch time of the round in which each client's
+            # update was last aggregated, and the realized round durations.
+            self.last_contact = np.full(self.num_units, -np.inf)
+            self.dur_sum, self.dur_n = 0.0, 0
+
+    def staleness_override(self) -> Optional[jax.Array]:
+        """Clock-measured staleness in model versions (None without a clock)."""
+        if not self.clocked:
+            return None
+        pol = self.eng.policy
+        ref = (self.dur_sum / self.dur_n if self.dur_n
+               else pol.latency.reference_time())
+        gap = pol.clock.now - self.last_contact
+        out = np.where(np.isfinite(gap), gap / ref, NEVER_STALE)
+        return jnp.asarray(out, jnp.float32)
+
+    def select(self, sk: jax.Array, t: int) -> List[Tuple[int, np.ndarray]]:
+        """The cohort as one pick (unit 0), or none."""
+        eng = self.eng
+        stale = (self.staleness_override(),) if self.clocked else ()
+        mask, _ = self._select(sk, eng.state, jnp.int32(t), *stale)
+        mask = np.asarray(mask)  # device sync — the selection phase ends
+        if self.clocked:
+            mask = mask & ~eng.policy.in_flight  # a busy client is not re-sent
+        selected = np.flatnonzero(mask)
+        return [(0, selected)] if len(selected) else []
+
+    def execute(self, picks: List[Tuple[int, np.ndarray]]) -> List[Update]:
+        """The cohort in one executor call; under the async policy each of
+        its clients is its own completion."""
+        out = []
+        for unit, ids in picks:
+            c = self.eng.train_cohort(ids)
+            if not self.clocked:
+                out.append(Update(unit, ids, c.mean_loss, c.update_sqnorm,
+                                  cohort=c))
+                continue
+            if c.param_list is None and c.stacked_params is None:
+                raise ExecutorCompatError(
+                    "async rounds need per-client updates, but the executor "
+                    "returned only the fused cohort mean")
+            w = (np.ones(len(ids)) if c.weights is None
+                 else np.asarray(c.weights, np.float64))
+            losses = np.asarray(c.mean_loss, np.float32).astype(np.float64)
+            sqnorms = np.asarray(c.update_sqnorm, np.float32).astype(np.float64)
+            for i, client in enumerate(ids):
+                params = (c.param_list[i] if c.param_list is not None else
+                          jax.tree_util.tree_map(lambda x: x[i], c.stacked_params))
+                out.append(Update(int(client), ids[i:i + 1], losses[i:i + 1],
+                                  sqnorms[i:i + 1], weight=float(w[i]),
+                                  params=params))
+        return out
+
+    def reduce(self, params: Any, out: List[Update]) -> Any:
+        return self.eng.aggregator.reduce(params, out[0].cohort) if out else params
+
+    def observe(self, arrived: List[Update]) -> None:
+        if not self.clocked:
+            return
+        pol = self.eng.policy
+        for u in arrived:
+            self.last_contact[u.clients] = pol.dispatch_time
+        self.dur_sum += pol.clock.now - pol.dispatch_time
+        self.dur_n += 1
+
+    # A pending client update persists as {loss, sqnorm, weight} beside its
+    # delta tree; the clock's event holds the client id.
+    @staticmethod
+    def pack(seq: int, u: Update, arrays: Dict[str, np.ndarray]) -> Dict[str, Any]:
+        return {"loss": float(u.losses[0]), "sqnorm": float(u.sqnorms[0]),
+                "weight": u.weight}
+
+    @staticmethod
+    def unpack(seq: int, unit: int, info: Dict[str, Any],
+               arrays: Dict[str, np.ndarray], delta: Any) -> Update:
+        return Update(unit, np.asarray([unit], np.int64),
+                      np.asarray([info["loss"]]), np.asarray([info["sqnorm"]]),
+                      weight=info["weight"], delta=delta)
+
+    def extra_state(self):
+        if not self.clocked:
+            return {}, {}, {}
+        # last_contact holds -inf for never-contacted clients: it must travel
+        # as an array shard, not JSON (which cannot encode infinities).
+        return ({}, {"last_contact": np.asarray(self.last_contact, np.float64)},
+                {"dur_sum": self.dur_sum, "dur_n": self.dur_n})
+
+    def load_extra_state(self, trees, arrays, meta):
+        if self.clocked:
+            self.last_contact = np.asarray(arrays["last_contact"],
+                                           np.float64).copy()
+            self.dur_sum = float(meta["extra"]["dur_sum"])
+            self.dur_n = int(meta["extra"]["dur_n"])
+
+
+class FederatedEngine:
+    """The one Algorithm-1 round over pluggable executor / aggregator / hooks.
+
+    One ``run()`` = ``fed.rounds`` rounds of: split key → select → execute →
+    aggregate → fold the arrived observations into ``ClientState`` → eval →
+    hooks, each phase once per round. What differs between the regimes is
+    data the engine holds (module docstring): ``topology``
+    (``FlatTopology`` | ``fed.hierarchy.HierarchicalTopology``) and
+    ``policy`` (``SyncPolicy`` | ``fed.async_engine.AsyncPolicy``).
+
+    The engine owns the skeleton and the resumable state (params, client
+    state, RNGs, byte counters); every behaviour beyond that is a plugin."""
 
     def __init__(self, spec: FederatedSpec):
         self.spec = spec
@@ -1185,16 +1405,7 @@ class FederatedEngine:
         self.aggregator = _resolve_aggregator(spec)
         self.hooks = _resolve_hooks(spec)
         self.metrics = next(h for h in self.hooks if isinstance(h, MetricsHook))
-
         self.selector_name = spec.resolved_selector
-        score_cfg = spec.score_cfg or HeteRoScoreConfig()
-        sel_cfg = spec.sel_cfg or SelectorConfig(num_selected=spec.fed.num_selected)
-        select = make_selector(self.selector_name, sel_cfg, score_cfg)
-        if spec.availability is not None:
-            select = fed_avail.mask_selector(
-                select, jnp.asarray(spec.availability),
-                num_selected=spec.fed.num_selected)
-        self._select = jax.jit(select)
 
         self.eval_fn = spec.eval_fn or default_eval
         self.metric_name = spec.metric_name or (
@@ -1206,11 +1417,6 @@ class FederatedEngine:
         # TraceRecorder at run start; the default no-op recorder makes
         # every instrumentation site one attribute read when disabled.
         self.tracer = NULL_TRACER
-        # Async subclasses replace this with a live VirtualClock in run();
-        # hooks discover it via ``getattr(engine, "clock", None)`` so the
-        # serving tier shares one event queue with async training and falls
-        # back to its own clock under the sync policy.
-        self.clock = None
         self.params: Any = None
         self.state: Optional[ClientState] = None
         self.key: Optional[jax.Array] = None
@@ -1219,6 +1425,44 @@ class FederatedEngine:
         self.wire_total = 0
         self.raw_total = 0
         self._rounds_done = 0
+
+        self.policy = self._choose_policy()
+        if spec.resolved_topology == "hierarchical":
+            from repro.fed.hierarchy import HierarchicalTopology
+
+            self.topology = HierarchicalTopology(self)
+        else:
+            self.topology = FlatTopology(self)
+
+    def _choose_policy(self) -> RoundPart:
+        """The round policy, with the aggregator rules that go with it."""
+        spec = self.spec
+        if spec.resolved_round_policy == "async":
+            from repro.fed.async_engine import AsyncPolicy
+
+            return AsyncPolicy(self)  # upgrades the aggregator
+        if spec.async_cfg is not None or spec.system is not None:
+            # The sync policy has no clock: silently modeling a homogeneous
+            # instant fleet while the config says otherwise is how wrong
+            # conclusions get drawn. Loud, like every other bad combination.
+            raise ValueError(
+                "async_cfg/system are only consumed by round_policy='async'; "
+                "the sync engine has no wall clock to apply them to")
+        if spec.resolved_topology == "hierarchical" \
+                and not isinstance(self.aggregator, (FedAvg, WeightedFedAvg)):
+            raise ValueError(
+                f"aggregator {getattr(self.aggregator, 'name', self.aggregator)!r} "
+                "does not compose with the hierarchical cloud stage "
+                "(edge aggregates combine as weighted deltas, not a "
+                "cohort reduce); use 'fedavg' or 'fedavg_weighted'")
+        return SyncPolicy(self)
+
+    @property
+    def clock(self) -> Any:
+        """The async policy's ``VirtualClock`` (None under sync). Hooks that
+        interleave with training time — the serving tier's PublishHook —
+        find it here and share its event queue."""
+        return self.policy.clock
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -1239,6 +1483,11 @@ class FederatedEngine:
         self.start_round = 0
         self._rounds_done = 0
         self.metrics.reset()  # before hooks — a resume hook repopulates these
+        # Before on_run_start, so hooks that interleave with training time
+        # can attach to the clock from the first round. A checkpoint resume
+        # replaces the clock (load_extra_state).
+        self.topology.start()
+        self.policy.start()
 
         ctx = RoundContext(engine=self)
         for h in self.hooks:
@@ -1261,57 +1510,74 @@ class FederatedEngine:
         return self._result(extras)
 
     def _run_round(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec, tr = self.spec, self.tracer
+        tr, topo, pol = self.tracer, self.topology, self.policy
         with phase("select", ctx, tr, args={"round": t}):
             self.key, sk = jax.random.split(self.key)
-            mask, _ = self._select(sk, self.state, jnp.int32(t))
-            mask_np = np.asarray(mask)  # device sync — the selection phase ends
-            selected = np.flatnonzero(mask_np)
-
+            picks = topo.select(sk, t)
+        mask = np.zeros(self.spec.data.num_clients, bool)  # who was dispatched
+        for _, ids in picks:
+            mask[ids] = True
         with phase("execute", ctx, tr,
-                   args={"round": t, "cohort": int(len(selected))}):
-            weights = self.aggregator.cohort_weights(selected, spec.data)
-            cohort = self.executor.run_round(self.params, selected, self.rng,
-                                             weights=weights)
-        with phase("aggregate", ctx, tr, args={"round": t}):
-            self.params = self.aggregator.reduce(self.params, cohort)
-            self.wire_total += cohort.wire_bytes
-            self.raw_total += cohort.raw_bytes
-
+                   args={"round": t, "cohort": sum(len(i) for _, i in picks)}):
+            out = topo.execute(picks)
+            pol.dispatch(t, out)
+        with phase("aggregate", ctx, tr, args={"round": t}) as agg:
+            arrived = pol.reduce(ctx, t, out, agg.args)
         with phase("state_update", recorder=tr, args={"round": t}):
-            obs_loss, obs_sqnorm = self._dense_observations(selected, cohort)
+            self._fold(ctx, t, arrived)
+            topo.observe(arrived)
+        ctx.mask, ctx.selected = mask, np.flatnonzero(mask)
+        with phase("eval", recorder=tr, args={"round": t}):
+            ctx.metric = self.eval_fn(self.spec.model, self.params, eval_batch)
+        self._rounds_done = t + 1
+
+    def train_cohort(self, ids: np.ndarray) -> CohortUpdates:
+        """One executor call for a cohort from the current global params."""
+        weights = self.aggregator.cohort_weights(ids, self.spec.data)
+        cohort = self.executor.run_round(self.params, ids, self.rng,
+                                         weights=weights)
+        self.wire_total += cohort.wire_bytes
+        self.raw_total += cohort.raw_bytes
+        return cohort
+
+    def _fold(self, ctx: RoundContext, t: int, arrived: List[Update]) -> None:
+        """Fold the arrived clients' losses and squared update norms into
+        ``ClientState`` (Algorithm 1, line 24). A cohort whose observations
+        are still on the device is scattered there and fetched once."""
+        k = self.spec.data.num_clients
+        if len(arrived) == 1:
+            u = arrived[0]
+            ids, losses, sqnorms = u.clients, u.losses, u.sqnorms
+        else:
+            ids = np.concatenate([u.clients for u in arrived] or [[]]).astype(np.int64)
+            losses = np.concatenate([u.losses for u in arrived] or [[]])
+            sqnorms = np.concatenate([u.sqnorms for u in arrived] or [[]])
+        if isinstance(losses, np.ndarray):
+            obs_loss = np.zeros(k, np.float32)
+            obs_sqnorm = np.zeros(k, np.float32)
+            obs_loss[ids] = losses
+            obs_sqnorm[ids] = sqnorms
+        else:
+            loss_j, sq_j = scatter_observations(
+                k, jnp.asarray(ids), losses, sqnorms)
+            obs_loss, obs_sqnorm = np.asarray(loss_j), np.asarray(sq_j)
+            losses = obs_loss[ids]
+        if len(ids):
+            mask = np.zeros(k, bool)
+            mask[ids] = True
             self.state = update_client_state(
                 self.state,
                 round_idx=jnp.int32(t),
-                selected_mask=jnp.asarray(mask_np),
+                selected_mask=jnp.asarray(mask),
                 observed_loss=jnp.asarray(obs_loss),
                 observed_sqnorm=jnp.asarray(obs_sqnorm),
             )
-
-        ctx.mask = mask_np
-        ctx.selected = selected
-        ctx.obs_loss = obs_loss
-        ctx.obs_sqnorm = obs_sqnorm
-        with phase("eval", recorder=tr, args={"round": t}):
-            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        ctx.train_loss = float(np.mean(obs_loss[selected])) if len(selected) else 0.0
-        ctx.sim_time = float(t + 1)  # sync rounds cost "1" on the time axis
-        self._rounds_done = t + 1
-
-    def _dense_observations(self, selected: np.ndarray,
-                            cohort: CohortUpdates) -> Tuple[np.ndarray, np.ndarray]:
-        k = self.spec.data.num_clients
-        if isinstance(cohort.mean_loss, np.ndarray):
-            obs_loss = np.zeros(k, np.float32)
-            obs_sqnorm = np.zeros(k, np.float32)
-            obs_loss[selected] = cohort.mean_loss
-            obs_sqnorm[selected] = cohort.update_sqnorm
-            return obs_loss, obs_sqnorm
-        loss_j, sq_j = scatter_observations(
-            k, jnp.asarray(selected), cohort.mean_loss, cohort.update_sqnorm)
-        return np.asarray(loss_j), np.asarray(sq_j)
+        ctx.obs_loss, ctx.obs_sqnorm = obs_loss, obs_sqnorm
+        ctx.train_loss = float(np.mean(losses)) if len(ids) else 0.0
 
     def _result(self, extras: Dict[str, Any]) -> FLResult:
+        for part in (self.topology, self.policy):
+            part.contribute(extras)
         sel_hist = np.stack(self.metrics.selected)
         return FLResult(
             accuracy=np.array(self.metrics.metric),
@@ -1336,40 +1602,16 @@ class FederatedEngine:
 
     # -- checkpoint / resume ----------------------------------------------
     #
-    # The base engine owns the snapshot layout (versioned + schema-checked,
-    # see repro.ckpt); subclasses contribute their per-round extras through
-    # the four-method ``extra_state`` protocol below instead of
-    # reimplementing save/restore. The snapshot records ``snapshot_kind`` so
-    # a resume against the wrong engine fails loudly before any leaf loads.
+    # The engine owns the snapshot layout (versioned + schema-checked, see
+    # repro.ckpt); the topology and the policy add their per-round state
+    # through ``RoundPart``'s ``extra_state`` protocol. The snapshot records
+    # ``snapshot_kind`` so a resume against the wrong engine fails loudly
+    # before any leaf loads.
 
     @property
     def snapshot_kind(self) -> str:
         """Engine identity stamped into (and verified against) snapshots."""
-        return "sync/flat"
-
-    def extra_state(self) -> Tuple[Dict[str, Any], Dict[str, np.ndarray],
-                                   Dict[str, Any]]:
-        """Subclass hook: extra ``(trees, arrays, meta)`` to persist.
-
-        Tree/array names share one namespace with the base snapshot
-        (``params``, ``client_state``, ``rng_key``, ``aggregator_state``;
-        ``metric``, ``train_loss``, ``selected_history``) — pick new ones.
-        The meta dict is stored under the snapshot's ``"extra"`` key and
-        handed back verbatim to ``extra_likes`` / ``load_extra_state``."""
-        return {}, {}, {}
-
-    def extra_likes(self, meta: Dict[str, Any]) -> Dict[str, Any]:
-        """Subclass hook: restore templates for ``extra_state`` trees.
-
-        Receives the snapshot's full meta (``meta["extra"]`` included)
-        *before* arrays load — the template set may depend on it (e.g. one
-        delta tree per in-flight completion, keyed by event seq)."""
-        return {}
-
-    def load_extra_state(self, trees: Dict[str, Any],
-                         arrays: Dict[str, np.ndarray],
-                         meta: Dict[str, Any]) -> None:
-        """Subclass hook: re-install restored extras into engine fields."""
+        return f"{self.policy.name}/{self.topology.name}"
 
     def save(self, path: str) -> str:
         """Write the full resumable state after the current round."""
@@ -1389,13 +1631,16 @@ class FederatedEngine:
             "execute_ms": np.asarray(self.metrics.execute_ms, np.float64),
             "aggregate_ms": np.asarray(self.metrics.aggregate_ms, np.float64),
         }
-        extra_trees, extra_arrays, extra_meta = self.extra_state()
-        clash = (set(trees) | {"aggregator_state"}) & set(extra_trees)
-        clash |= set(arrays) & set(extra_arrays)
-        if clash:
-            raise ValueError(f"extra_state name collision: {sorted(clash)}")
-        trees.update(extra_trees)
-        arrays.update(extra_arrays)
+        extra_meta: Dict[str, Any] = {}
+        for part in (self.topology, self.policy):
+            extra_trees, extra_arrays, part_meta = part.extra_state()
+            clash = (set(trees) | {"aggregator_state"}) & set(extra_trees)
+            clash |= set(arrays) & set(extra_arrays)
+            if clash:
+                raise ValueError(f"extra_state name collision: {sorted(clash)}")
+            trees.update(extra_trees)
+            arrays.update(extra_arrays)
+            extra_meta.update(part_meta)
         hook_states = {str(i): s for i, h in enumerate(self.hooks)
                        if (s := h.state_dict()) is not None}
         meta = {
@@ -1438,7 +1683,8 @@ class FederatedEngine:
                 lambda x: x.astype(jnp.float32), self.params)
         likes = {"params": self.params, "client_state": self.state,
                  "rng_key": self.key, "aggregator_state": agg_like}
-        likes.update(self.extra_likes(head))
+        for part in (self.topology, self.policy):
+            likes.update(part.extra_likes(head))
         trees, arrays, meta = repro_ckpt.restore_federated_round(
             path, likes=likes, round_idx=int(head["round"]),
             optional=("aggregator_state",))
@@ -1463,7 +1709,8 @@ class FederatedEngine:
             i = int(i_str)
             if i < len(self.hooks):
                 self.hooks[i].load_state_dict(s)
-        self.load_extra_state(trees, arrays, meta)
+        for part in (self.topology, self.policy):
+            part.load_extra_state(trees, arrays, meta)
         self.start_round = int(meta["round"])
         self._rounds_done = self.start_round
         return self.start_round

@@ -6,7 +6,8 @@ to the cloud over the expensive WAN. The client-selection survey (Fu et al.,
 arXiv:2211.01549) and heterogeneity-guided sampling (Chen & Vikalo,
 arXiv:2310.00198) both identify this grouped regime as where smart
 participation compounds: selection happens *twice*, within edges and across
-edges. ``HierarchicalEngine`` is that topology on the PR-3 plugin surface:
+edges. ``HierarchicalTopology`` is that topology of the one round in
+``fed.engine``:
 
   1. **Partition** — the K clients split into E edge groups once per run
      (``fed.partition.partition_edges``): by label-skew similarity (clients
@@ -30,20 +31,21 @@ edges. ``HierarchicalEngine`` is that topology on the PR-3 plugin surface:
      call (the batched vmap path stays the compute substrate) and reduces to
      the edge aggregate (``fed.server.fedavg_fused`` under the batched
      executor); the cloud then combines edge aggregates as size-weighted
-     deltas (``fed.server.apply_weighted_deltas``). Only E aggregates cross
+     deltas (``fed.server.combine_edges``). Only E aggregates cross
      the WAN per round instead of m client updates —
      ``FLResult.cloud_uploads`` is that axis, benchmarked against flat
      selection by ``benchmarks/table7_hierarchy.py``.
 
 Both round policies compose (``FedConfig.round_policy``):
 
-  * **sync** — edge rounds are barriers: every active edge's aggregate
-    reaches the cloud in its dispatch round.
-  * **async** — each edge is one event on the PR-4 ``VirtualClock``: the
-    edge completes at the max of its cohort's latencies, the cloud closes
-    the round at ``AsyncConfig.deadline``, and straggler edges carry forward
-    as stale cloud arrivals discounted by the FedBuff weight (1+τ)^(−a)
-    (``BufferedAggregator``). In-flight edges are not re-dispatched.
+  * **sync** (``fed.engine.SyncPolicy``) — edge rounds are barriers: every
+    active edge's aggregate reaches the cloud in its dispatch round.
+  * **async** (``fed.async_engine.AsyncPolicy``) — each edge is one event on
+    the ``VirtualClock``: the edge completes at the max of its cohort's
+    latencies, the cloud closes the round at ``AsyncConfig.deadline``, and
+    straggler edges carry forward as stale cloud arrivals discounted by the
+    FedBuff weight (1+τ)^(−a) (``BufferedAggregator``). In-flight edges are
+    not re-dispatched.
 
 Degenerate-equivalence contract: with E = 1 and the full budget m the inner
 selection *is* flat selection (same selector config, same key, the identity
@@ -62,12 +64,12 @@ as the jnp path, so the selection history matches ``selector='heterosel'``
 
 ``CheckpointHook`` composes with both policies: the cloud-upload series —
 and in async mode the virtual clock with its in-flight edge cohorts — are
-part of the versioned round snapshot via the engine's ``extra_state``
-protocol, so a killed hierarchical run resumes bitwise
+part of the versioned round snapshot via the ``extra_state`` protocol
+(``fed.engine.RoundPart``), so a killed hierarchical run resumes bitwise
 (tests/test_resume_matrix.py).
 
 ``availability`` masks (device churn) thread through the *inner* stage:
-each edge's selector is wrapped with the flat engine's
+each edge's selector is wrapped with the flat topology's
 ``fed.availability.mask_selector`` over the edge-local mask columns (the
 segmented heterosel_pallas path applies the same ``_remask`` host-side), so
 offline members are never selected and a fully-offline edge simply yields
@@ -87,8 +89,7 @@ resumed runs stay bitwise.
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -105,25 +106,15 @@ from repro.core.selection import (
     make_selector,
     sample_clients,
 )
-from repro.core.state import pool_client_state, update_client_state
+from repro.core.state import pool_client_state
 from repro import ckpt as repro_ckpt
 from repro.fed import availability as fed_avail
 from repro.fed import server as fed_server
-from repro.fed.async_engine import (
-    AsyncConfig,
-    _resolve_multipliers,
-    drain_due_arrivals,
-    upgrade_async_aggregator,
-)
-from repro.fed.clock import LatencyModel, VirtualClock
 from repro.fed.engine import (
-    CohortUpdates,
-    FedAvg,
     FederatedEngine,
-    FederatedSpec,
-    FLResult,
-    RoundContext,
-    WeightedFedAvg,
+    RoundPart,
+    Update,
+    cohort_mean,
 )
 from repro.fed.partition import EdgePartition, partition_edges
 from repro.obs.phases import phase
@@ -183,33 +174,18 @@ def edge_budgets(num_selected: int, sizes: np.ndarray,
     return base
 
 
-@dataclasses.dataclass
-class EdgeCohort:
-    """One edge's inner-round outcome on its way to the cloud."""
+class HierarchicalTopology(RoundPart):
+    """Topology 'hierarchical' (``FedConfig.topology``): clients → edges →
+    cloud, under either round policy (module docstring). The unit of
+    dispatch and of cloud arrival is an edge aggregate, not a client
+    update."""
 
-    edge: int
-    selected: np.ndarray       # global client ids of the edge cohort
-    losses: np.ndarray         # (m_e,) per-client mean local loss
-    sqnorms: np.ndarray        # (m_e,) per-client ||Δw||²
-    weight: float              # cloud combine weight (cohort size / |D| sum)
-    avg_params: Any = None     # the edge aggregate (sync path)
-    delta: Any = None          # f32 edge aggregate − dispatch anchor (async)
+    name = "hierarchical"
+    in_flight_key = "edge_in_flight"
 
-
-class HierarchicalEngine(FederatedEngine):
-    """Two-tier rounds over the plugin surface (``FedConfig.topology``).
-
-    Built by ``FederatedSpec.build()`` when the resolved topology is
-    ``'hierarchical'``. Handles both round policies itself: sync edge
-    barriers, or async edge events on a ``VirtualClock`` with deadline-closed
-    cloud rounds — flat mode's ``AsyncFederatedEngine`` is *not* stacked
-    underneath, because the unit of cloud arrival here is an edge aggregate,
-    not a client update.
-    """
-
-    def __init__(self, spec: FederatedSpec):
-        super().__init__(spec)
-        fed = spec.fed
+    def __init__(self, eng: FederatedEngine):
+        self.eng = eng
+        spec, fed = eng.spec, eng.spec.fed
         self._avail = (None if spec.availability is None
                        else np.asarray(spec.availability, bool))
         if fed.edge_count < 1:
@@ -217,14 +193,13 @@ class HierarchicalEngine(FederatedEngine):
                 "topology='hierarchical' requires FedConfig.edge_count ≥ 1 "
                 f"(got {fed.edge_count}); set edge_count=E or topology='flat'")
         self.hcfg: HierarchyConfig = spec.hier_cfg or HierarchyConfig()
-        self.policy = spec.resolved_round_policy
 
         seed = (fed.seed if self.hcfg.partition_seed is None
                 else self.hcfg.partition_seed)
         self.partition: EdgePartition = partition_edges(
             np.asarray(spec.data.label_js), fed.edge_count,
             mode=self.hcfg.partition_mode, seed=seed)
-        self.edge_count = self.partition.edge_count
+        self.edge_count = self.num_units = self.partition.edge_count
         self._members = self.partition.member_lists()
         self._assignment = jnp.asarray(self.partition.assignment)
         self.budgets = edge_budgets(
@@ -233,7 +208,7 @@ class HierarchicalEngine(FederatedEngine):
         self._score_cfg = spec.score_cfg or HeteRoScoreConfig()
         base_sel = spec.sel_cfg or SelectorConfig(num_selected=fed.num_selected)
         self._base_sel = base_sel
-        base_name = base_selector_name(self.selector_name)
+        base_name = base_selector_name(eng.selector_name)
         # Outer-stage selector semantics follow the configured selector
         # family so hierarchical baselines stay uncontaminated: HeteRo
         # variants score pooled edges (additive or multiplicative to match;
@@ -246,7 +221,7 @@ class HierarchicalEngine(FederatedEngine):
         outer_active = 0 < self.hcfg.edges_per_round < self.edge_count
         if outer_active and base_name in ("oort", "power_of_choice"):
             raise ValueError(
-                f"selector={self.selector_name!r} has no edge-level analogue "
+                f"selector={eng.selector_name!r} has no edge-level analogue "
                 "for the outer cross-edge stage; with edges_per_round < "
                 "edge_count use a 'heterosel*' selector or 'random' (or set "
                 "edges_per_round=0 to dispatch every edge)")
@@ -258,7 +233,7 @@ class HierarchicalEngine(FederatedEngine):
         # budgets online (module docstring): mean edge-cohort losses feed an
         # AdaptiveBudgets controller whose re-apportionment replaces the
         # static size-proportional split after the first observation.
-        self._budget_ctl: Optional[AdaptiveBudgets] = None
+        self.budget_ctl: Optional[AdaptiveBudgets] = None
         if base_name == "adaptive":
             if fed.edge_budget > 0:
                 raise ValueError(
@@ -266,8 +241,8 @@ class HierarchicalEngine(FederatedEngine):
                     "from the global num_selected; an explicit "
                     "FedConfig.edge_budget conflicts with that — unset it "
                     "or use a non-adaptive selector")
-            self._budget_ctl = AdaptiveBudgets(fed.num_selected,
-                                               self.partition.sizes)
+            self.budget_ctl = AdaptiveBudgets(fed.num_selected,
+                                              self.partition.sizes)
         # Inner-selection machinery. heterosel_pallas scores every edge in
         # ONE segmented kernel launch (_seg_probs below); everything else
         # gets one jitted per-edge selector per distinct (edge size, budget)
@@ -279,30 +254,10 @@ class HierarchicalEngine(FederatedEngine):
         self._edge_select: Dict[int, Any] = {}
         self._sel_cache: Dict[Any, Any] = {}
         self._seg_probs: Optional[Any] = None
-        if self.selector_name == "heterosel_pallas":
+        if eng.selector_name == "heterosel_pallas":
             self._init_segmented_selection(base_sel)
         else:
             self._build_edge_selectors()
-
-        if self.policy == "async":
-            self.acfg: AsyncConfig = spec.async_cfg or AsyncConfig()
-            mult = _resolve_multipliers(spec.system, spec.data.num_clients)
-            self.latency = LatencyModel(mult, base=self.acfg.base_latency,
-                                        jitter=self.acfg.jitter)
-            self.aggregator = upgrade_async_aggregator(self.aggregator,
-                                                       self.acfg)
-        else:
-            if spec.async_cfg is not None or spec.system is not None:
-                raise ValueError(
-                    "async_cfg/system are only consumed by "
-                    "round_policy='async'; the sync engine has no wall clock "
-                    "to apply them to")
-            if not isinstance(self.aggregator, (FedAvg, WeightedFedAvg)):
-                raise ValueError(
-                    f"aggregator {getattr(self.aggregator, 'name', self.aggregator)!r} "
-                    "does not compose with the hierarchical cloud stage "
-                    "(edge aggregates combine as weighted deltas, not a "
-                    "cohort reduce); use 'fedavg' or 'fedavg_weighted'")
 
     def _init_segmented_selection(self, base_sel: SelectorConfig) -> None:
         """The heterosel_pallas inner-stage fast path: one segmented kernel.
@@ -357,10 +312,10 @@ class HierarchicalEngine(FederatedEngine):
                          else ("avail", e, b))
             if cache_key not in self._sel_cache:
                 cfg_e = dataclasses.replace(self._base_sel, num_selected=b)
-                select = make_selector(self.selector_name, cfg_e,
+                select = make_selector(self.eng.selector_name, cfg_e,
                                        self._score_cfg)
                 if self._avail is not None:
-                    # Same semantics as the flat engine's churn path: zero
+                    # Same semantics as the flat topology's churn path: zero
                     # offline mass, renormalize, resample inside the edge.
                     select = fed_avail.mask_selector(
                         select,
@@ -369,26 +324,12 @@ class HierarchicalEngine(FederatedEngine):
                 self._sel_cache[cache_key] = jax.jit(select)
             self._edge_select[e] = self._sel_cache[cache_key]
 
-    # -- lifecycle ---------------------------------------------------------
-
-    def run(self) -> FLResult:
-        self.cloud_uploads: List[int] = []
-        if self.policy == "async":
-            self.clock = VirtualClock()
-            self._edge_in_flight = np.zeros(self.edge_count, bool)
-            self.wall_clock: List[float] = []
-            self.round_staleness: List[float] = []
-            self.stragglers_carried = 0
-            self.updates_dropped = 0
-        return super().run()
-
     # -- the two selection stages ------------------------------------------
 
     def _idle_edges(self) -> List[int]:
-        busy = (self._edge_in_flight if self.policy == "async"
-                else np.zeros(self.edge_count, bool))
+        busy = self.eng.policy.in_flight
         return [e for e in range(self.edge_count)
-                if self.budgets[e] > 0 and not busy[e]]
+                if self.budgets[e] > 0 and (busy is None or not busy[e])]
 
     def _choose_edges(self, sk: jax.Array, t: int, idle: List[int]) -> List[int]:
         """Outer cross-edge selection over the idle edges.
@@ -404,15 +345,14 @@ class HierarchicalEngine(FederatedEngine):
         harvests ~E_sel aggregates when a straggler edge misses it — the
         edge-level mirror of flat async's client over-selection.
         """
-        e_sel = self.hcfg.edges_per_round or self.edge_count
-        if self.policy == "async":
-            e_sel = int(math.ceil(e_sel * (1.0 + self.acfg.over_select_frac)))
+        e_sel = self.eng.policy.over_select(
+            self.hcfg.edges_per_round or self.edge_count, self.edge_count)
         if e_sel >= len(idle):
             return list(idle)
         if self._outer_uniform:  # selector='random': uniform edge choice too
             probs = np.full(self.edge_count, 1.0 / self.edge_count)
         else:
-            pooled = pool_client_state(self.state, self._assignment,
+            pooled = pool_client_state(self.eng.state, self._assignment,
                                        self.edge_count)
             probs = np.asarray(edge_selection_probs(
                 pooled, jnp.int32(t), self._outer_sel_cfg, self._score_cfg),
@@ -446,94 +386,68 @@ class HierarchicalEngine(FederatedEngine):
         """
         picks: List[tuple] = []
         if self._seg_probs is not None:
-            probs_all = np.asarray(self._seg_probs(self.state, jnp.int32(t)))
-            for e in active:
-                members = self._members[e]
+            probs_all = np.asarray(self._seg_probs(self.eng.state, jnp.int32(t)))
+        for e in active:
+            members, budget = self._members[e], int(self.budgets[e])
+            if self._seg_probs is None:
+                idx = jnp.asarray(members)
+                estate = jax.tree_util.tree_map(lambda x: x[idx], self.eng.state)
+                mask_local, _ = self._edge_select[e](keys[e], estate,
+                                                     jnp.int32(t))
+            else:
                 probs_e = jnp.asarray(
                     probs_all[e * self._seg:e * self._seg + len(members)])
-                if self._avail is not None:
+                if self._avail is None:
+                    mask_local = sample_clients(keys[e], probs_e, budget)
+                else:
                     # Host-side mirror of mask_selector: the same _remask
                     # (fold_in(key, 1) draw) on the kernel's probabilities,
                     # so the cohort matches the jnp churn path exactly.
                     mask_local, _ = fed_avail._remask(
                         keys[e], probs_e,
-                        jnp.asarray(self._avail[t][members]),
-                        int(self.budgets[e]))
-                else:
-                    mask_local = sample_clients(keys[e], probs_e,
-                                                int(self.budgets[e]))
-                sel_local = np.flatnonzero(np.asarray(mask_local))
-                if len(sel_local):
-                    picks.append((e, members[sel_local]))
-            return picks
-        for e in active:
-            members = self._members[e]
-            idx = jnp.asarray(members)
-            estate = jax.tree_util.tree_map(lambda x: x[idx], self.state)
-            mask_local, _ = self._edge_select[e](keys[e], estate, jnp.int32(t))
+                        jnp.asarray(self._avail[t][members]), budget)
             sel_local = np.flatnonzero(np.asarray(mask_local))
             if len(sel_local):
                 picks.append((e, members[sel_local]))
         return picks
 
-    def _inner_execute(self, picks: List[tuple]) -> List[EdgeCohort]:
-        """One executor call per selected edge cohort."""
-        out: List[EdgeCohort] = []
-        for e, sel_global in picks:
-            with phase("edge_execute", recorder=self.tracer, track="edges",
-                       args={"edge": int(e), "cohort": int(len(sel_global))}):
-                weights = self.aggregator.cohort_weights(sel_global,
-                                                         self.spec.data)
-                cohort = self.executor.run_round(self.params, sel_global,
-                                                 self.rng, weights=weights)
-                self.wire_total += cohort.wire_bytes
-                self.raw_total += cohort.raw_bytes
-                ew = (float(len(sel_global)) if weights is None
-                      else float(np.sum(np.asarray(weights, np.float64))))
+    def select(self, sk: jax.Array, t: int) -> List[Tuple[int, np.ndarray]]:
+        """Outer (edge) then inner (per-edge client) selection: the (edge,
+        global cohort ids) picks."""
+        tr = self.eng.tracer
+        with phase("outer_select", recorder=tr, args={"round": t}) as outer:
+            active = self._choose_edges(sk, t, self._idle_edges())
+            outer.args["edges"] = int(len(active))
+        with phase("inner_select", recorder=tr, args={"round": t}):
+            return self._inner_select(active, self._inner_keys(sk), t)
+
+    def execute(self, picks: List[Tuple[int, np.ndarray]]) -> List[Update]:
+        """One executor call per edge cohort, reduced to the edge aggregate
+        (the cohort's weighted mean, ``cohort_mean``). An edge completes as
+        one unit: its cohort is an internal barrier."""
+        out: List[Update] = []
+        for e, ids in picks:
+            with phase("edge_execute", recorder=self.eng.tracer, track="edges",
+                       args={"edge": int(e), "cohort": int(len(ids))}):
+                cohort = self.eng.train_cohort(ids)
+                ew = (float(len(ids)) if cohort.weights is None
+                      else float(np.sum(np.asarray(cohort.weights, np.float64))))
                 losses = np.asarray(cohort.mean_loss, np.float32)
                 sqnorms = np.asarray(cohort.update_sqnorm, np.float32)
                 with phase("aggregate"):
-                    avg_params = self.aggregator._mean(cohort)
-                out.append(EdgeCohort(edge=e, selected=sel_global, losses=losses,
-                                      sqnorms=sqnorms, weight=ew,
-                                      avg_params=avg_params))
+                    avg_params = cohort_mean(cohort)
+                out.append(Update(e, ids, losses, sqnorms, weight=ew,
+                                  params=avg_params))
         return out
 
-    # -- observation fold (shared by both policies) ------------------------
+    def reduce(self, params: Any, out: List[Update]) -> Any:
+        return fed_server.combine_edges(params, [u.params for u in out],
+                                        [u.weight for u in out])
 
-    def _fold_observations(self, ctx: RoundContext, t: int,
-                           cohorts: List[EdgeCohort],
-                           dispatched_mask: Optional[np.ndarray] = None) -> None:
-        k = self.spec.data.num_clients
-        mask = np.zeros(k, bool)
-        obs_loss = np.zeros(k, np.float32)
-        obs_sqnorm = np.zeros(k, np.float32)
-        all_losses: List[np.ndarray] = []
-        for c in cohorts:
-            mask[c.selected] = True
-            obs_loss[c.selected] = c.losses
-            obs_sqnorm[c.selected] = c.sqnorms
-            all_losses.append(c.losses)
-        if mask.any():
-            self.state = update_client_state(
-                self.state,
-                round_idx=jnp.int32(t),
-                selected_mask=jnp.asarray(mask),
-                observed_loss=jnp.asarray(obs_loss),
-                observed_sqnorm=jnp.asarray(obs_sqnorm),
-            )
-        ctx.mask = mask if dispatched_mask is None else dispatched_mask
-        ctx.selected = np.flatnonzero(ctx.mask)
-        ctx.obs_loss = obs_loss
-        ctx.obs_sqnorm = obs_sqnorm
-        ctx.train_loss = (float(np.concatenate(all_losses).mean())
-                          if all_losses else 0.0)
-        if self._budget_ctl is not None and cohorts:
-            self._retune_budgets(cohorts)
-
-    def _retune_budgets(self, cohorts: List[EdgeCohort]) -> None:
-        """Feed one round's mean edge-cohort losses to `AdaptiveBudgets`;
-        rebind the per-edge selectors only when the apportionment moved.
+    def observe(self, arrived: List[Update]) -> None:
+        """Count the cloud uploads; under ``selector='adaptive'`` feed the
+        round's mean edge-cohort losses to `AdaptiveBudgets` and rebind the
+        per-edge selectors only when the apportionment moved.
 
         NaN marks edges that reported nothing this round — the controller
         keeps their previous smoothed estimate (never zeroes them out).
@@ -541,176 +455,60 @@ class HierarchicalEngine(FederatedEngine):
         history stays seed-deterministic and resume-bitwise (the
         controller state persists via ``extra_state``).
         """
+        self.cloud_uploads.append(len(arrived))
+        if self.budget_ctl is None or not arrived:
+            return
         util = np.full(self.edge_count, np.nan)
-        for c in cohorts:
-            util[c.edge] = float(np.asarray(c.losses, np.float64).mean())
-        new_budgets = self._budget_ctl.observe_round(util)
+        for u in arrived:
+            util[u.unit] = float(np.asarray(u.losses, np.float64).mean())
+        new_budgets = self.budget_ctl.observe_round(util)
         if not np.array_equal(new_budgets, self.budgets):
             self.budgets = new_budgets
             self._build_edge_selectors()
 
-    # -- rounds ------------------------------------------------------------
+    def start(self) -> None:
+        self.cloud_uploads: List[int] = []
 
-    def _run_round(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        if self.policy == "async":
-            self._run_round_async(ctx, t, eval_batch)
-        else:
-            self._run_round_sync(ctx, t, eval_batch)
-
-    def _select_phase(self, ctx: RoundContext, t: int) -> List[tuple]:
-        """Outer (edge) then inner (per-edge client) selection; returns the
-        (edge, global cohort ids) picks."""
-        tr = self.tracer
-        with phase("select", ctx, tr, args={"round": t}):
-            self.key, sk = jax.random.split(self.key)
-            with phase("outer_select", recorder=tr, args={"round": t}) as outer:
-                active = self._choose_edges(sk, t, self._idle_edges())
-                outer.args["edges"] = int(len(active))
-            with phase("inner_select", recorder=tr, args={"round": t}):
-                picks = self._inner_select(active, self._inner_keys(sk), t)
-        return picks
-
-    def _run_round_sync(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec, tr = self.spec, self.tracer
-        picks = self._select_phase(ctx, t)
-        with phase("execute", ctx, tr, args={"round": t, "edges": int(len(picks))}):
-            cohorts = self._inner_execute(picks)
-
-        with phase("aggregate", ctx, tr,
-                   args={"round": t, "cloud_uploads": int(len(cohorts))}):
-            if len(cohorts) == 1:
-                # The weighted mean of one edge aggregate is that aggregate —
-                # taken bitwise, which is what pins the E=1 flat-equivalence
-                # contract (no f32 round-trip through the delta form).
-                self.params = cohorts[0].avg_params
-            elif cohorts:
-                deltas = [fed_server.params_delta_f32(c.avg_params, self.params)
-                          for c in cohorts]
-                w = jnp.asarray([c.weight for c in cohorts], jnp.float32)
-                self.params = fed_server.apply_weighted_deltas(
-                    self.params, deltas, w)
-            self.cloud_uploads.append(len(cohorts))
-
-        with phase("state_update", recorder=tr, args={"round": t}):
-            self._fold_observations(ctx, t, cohorts)
-        with phase("eval", recorder=tr, args={"round": t}):
-            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        ctx.sim_time = float(t + 1)  # sync rounds cost "1" on the time axis
-        self._rounds_done = t + 1
-
-    def _run_round_async(self, ctx: RoundContext, t: int, eval_batch: Any) -> None:
-        spec, acfg, tr = self.spec, self.acfg, self.tracer
-        dispatch_time = self.clock.now
-
-        # 1.–2. Dispatch idle edges; each trains now but its aggregate
-        # arrives at the cloud after the max of its cohort's latencies
-        # (the edge is an internal barrier).
-        picks = self._select_phase(ctx, t)
-        dispatched = np.zeros(spec.data.num_clients, bool)
-        with phase("execute", ctx, tr, args={"round": t, "edges": int(len(picks))}):
-            for c in self._inner_execute(picks):
-                c.delta = fed_server.params_delta_f32(c.avg_params, self.params)
-                c.avg_params = None  # the anchor-relative delta is what travels
-                lat = float(self.latency.sample(c.selected, self.rng).max())
-                self.clock.schedule(lat, c.edge, t, payload=c)
-                self._edge_in_flight[c.edge] = True
-                dispatched[c.selected] = True
-
-        # 3. Close the cloud round at the deadline (the shared flat-async
-        # semantics — drain_due_arrivals); straggler edges carry forward as
-        # stale arrivals.
-        # 4. Buffered aggregation of the arrived edge aggregates.
-        with phase("aggregate", ctx, tr, args={"round": t}) as agg:
-            kept, dropped = drain_due_arrivals(self.clock, acfg, t, dispatch_time,
-                                               self._edge_in_flight)
-            self.updates_dropped += dropped
-            stale = np.asarray([t - ev.dispatch_round for ev in kept], np.float32)
-            n_stragglers = sum(1 for ev in kept if ev.dispatch_round < t)
-            agg.args.update(cloud_uploads=int(len(kept)), stragglers=n_stragglers)
-            arrivals = [ev.payload for ev in kept]
-            if kept:
-                agg_cohort = CohortUpdates(
-                    mean_loss=np.asarray([c.losses.mean() for c in arrivals],
-                                         np.float32),
-                    update_sqnorm=np.asarray([c.sqnorms.mean() for c in arrivals],
-                                             np.float32),
-                    delta_list=[c.delta for c in arrivals],
-                    staleness=stale,
-                    weights=np.asarray([c.weight for c in arrivals], np.float32),
-                )
-                self.params = self.aggregator.reduce(self.params, agg_cohort)
-            self.cloud_uploads.append(len(kept))
-
-        with phase("state_update", recorder=tr, args={"round": t}):
-            self._fold_observations(ctx, t, arrivals, dispatched_mask=dispatched)
-
-        self.stragglers_carried += n_stragglers
-        self.wall_clock.append(self.clock.now)
-        self.round_staleness.append(float(stale.mean()) if len(stale) else 0.0)
-        ctx.sim_time = self.clock.now
-        ctx.num_arrivals = len(kept)
-        ctx.num_stragglers = n_stragglers
-        with phase("eval", recorder=tr, args={"round": t}):
-            ctx.metric = self.eval_fn(spec.model, self.params, eval_batch)
-        self._rounds_done = t + 1
-
-    def _result(self, extras) -> FLResult:
+    def contribute(self, extras: Dict[str, Any]) -> None:
         extras.setdefault("cloud_uploads",
                           np.asarray(self.cloud_uploads, np.int64))
-        if self.policy == "async":
-            extras.setdefault("wall_clock", np.asarray(self.wall_clock))
-            extras.setdefault("round_staleness",
-                              np.asarray(self.round_staleness))
-        return super()._result(extras)
 
     # -- checkpoint / resume ----------------------------------------------
     #
     # The edge partition itself is deterministic from the spec (label_js +
     # edge_count + partition mode/seed), so it is rebuilt, not persisted —
     # only its shape is stamped into the snapshot as a sanity check. What
-    # does persist via the extra_state protocol: the cloud-upload series,
-    # and in async mode the virtual clock with each in-flight EdgeCohort
-    # (delta pytree as its own schema-checked tree; cohort ids / losses /
-    # sqnorms as per-seq arrays) plus the in-flight edge mask and the
-    # wall-clock series. The snapshot kind embeds the round policy, so an
-    # async/hierarchical snapshot never restores into a sync engine.
+    # does persist: the cloud-upload series and the adaptive budgets; under
+    # the async policy, each in-flight edge's cohort ids, losses and
+    # sqnorms as per-seq arrays beside its delta tree.
 
-    @property
-    def snapshot_kind(self) -> str:
-        return f"{self.policy}/hierarchical"
+    @staticmethod
+    def pack(seq: int, u: Update, arrays: Dict[str, np.ndarray]) -> Dict[str, Any]:
+        arrays[f"pending_sel/{seq}"] = np.asarray(u.clients, np.int64)
+        arrays[f"pending_loss/{seq}"] = np.asarray(u.losses, np.float32)
+        arrays[f"pending_sqnorm/{seq}"] = np.asarray(u.sqnorms, np.float32)
+        return {"edge": u.unit, "weight": u.weight}
+
+    @staticmethod
+    def unpack(seq: int, unit: int, info: Dict[str, Any],
+               arrays: Dict[str, np.ndarray], delta: Any) -> Update:
+        return Update(
+            int(info["edge"]),
+            np.asarray(arrays[f"pending_sel/{seq}"], np.int64),
+            np.asarray(arrays[f"pending_loss/{seq}"], np.float32),
+            np.asarray(arrays[f"pending_sqnorm/{seq}"], np.float32),
+            weight=float(info["weight"]), delta=delta)
 
     def extra_state(self):
-        trees: Dict[str, Any] = {}
         arrays: Dict[str, np.ndarray] = {
             "cloud_uploads": np.asarray(self.cloud_uploads, np.int64),
         }
-        meta: Dict[str, Any] = {"edge_count": self.edge_count}
-        if self._budget_ctl is not None:
+        if self.budget_ctl is not None:
             arrays["budgets"] = np.asarray(self.budgets, np.int64)
-            util = self._budget_ctl.utilities
+            util = self.budget_ctl.utilities
             if util is not None:
                 arrays["budget_util"] = util
-        if self.policy == "async":
-            pending_meta: Dict[str, Any] = {}
-            for ev in self.clock.pending():
-                c = ev.payload
-                trees[f"pending/{ev.seq}"] = c.delta
-                arrays[f"pending_sel/{ev.seq}"] = np.asarray(c.selected,
-                                                             np.int64)
-                arrays[f"pending_loss/{ev.seq}"] = np.asarray(c.losses,
-                                                              np.float32)
-                arrays[f"pending_sqnorm/{ev.seq}"] = np.asarray(c.sqnorms,
-                                                                np.float32)
-                pending_meta[str(ev.seq)] = {"edge": c.edge,
-                                             "weight": c.weight}
-            arrays["edge_in_flight"] = self._edge_in_flight
-            arrays["wall_clock"] = np.asarray(self.wall_clock, np.float64)
-            arrays["round_staleness"] = np.asarray(self.round_staleness,
-                                                   np.float64)
-            meta.update(clock=self.clock.state_dict(), pending=pending_meta,
-                        stragglers_carried=self.stragglers_carried,
-                        updates_dropped=self.updates_dropped)
-        return trees, arrays, meta
+        return {}, arrays, {"edge_count": self.edge_count}
 
     def extra_likes(self, meta):
         extra = meta["extra"]
@@ -720,44 +518,23 @@ class HierarchicalEngine(FederatedEngine):
                 f"{extra.get('edge_count')}, this engine partitions into "
                 f"{self.edge_count} edges — resume with the same "
                 "FedConfig.edge_count")
-        if self.policy != "async":
-            return {}
-        # In-flight edge deltas share the params structure but are always
-        # f32 (params_delta_f32), whatever dtype the model params use.
-        delta_like = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, jnp.float32), self.params)
-        return {f"pending/{ev['seq']}": delta_like
-                for ev in extra["clock"]["events"]}
+        return {}
 
     def load_extra_state(self, trees, arrays, meta):
-        extra = meta["extra"]
         self.cloud_uploads = [int(x) for x in arrays["cloud_uploads"]]
-        if self._budget_ctl is not None:
+        if self.budget_ctl is not None:
             util = arrays.get("budget_util")
-            self._budget_ctl.load_state_dict(
+            self.budget_ctl.load_state_dict(
                 {"util": None if util is None else np.asarray(util)})
             self.budgets = np.asarray(arrays["budgets"], np.int64)
             if self._seg_probs is None:
                 self._build_edge_selectors()
-        if self.policy != "async":
-            return
-        payloads = {
-            int(seq): EdgeCohort(
-                edge=int(info["edge"]),
-                selected=np.asarray(arrays[f"pending_sel/{seq}"], np.int64),
-                losses=np.asarray(arrays[f"pending_loss/{seq}"], np.float32),
-                sqnorms=np.asarray(arrays[f"pending_sqnorm/{seq}"],
-                                   np.float32),
-                weight=float(info["weight"]),
-                avg_params=None,
-                delta=trees[f"pending/{seq}"])
-            for seq, info in extra["pending"].items()
-        }
-        self.clock = VirtualClock()
-        self.clock.load_state_dict(extra["clock"], payloads)
-        self._edge_in_flight = np.asarray(arrays["edge_in_flight"],
-                                          bool).copy()
-        self.wall_clock = [float(x) for x in arrays["wall_clock"]]
-        self.round_staleness = [float(x) for x in arrays["round_staleness"]]
-        self.stragglers_carried = int(extra["stragglers_carried"])
-        self.updates_dropped = int(extra["updates_dropped"])
+
+
+class HierarchicalEngine(FederatedEngine):
+    """What ``FederatedSpec.build()`` returns for topology='hierarchical',
+    under either round policy: ``FederatedEngine``'s round over a
+    ``HierarchicalTopology``."""
+
+    partition = property(lambda self: self.topology.partition)
+    budgets = property(lambda self: self.topology.budgets)

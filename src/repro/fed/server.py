@@ -108,6 +108,21 @@ def apply_weighted_deltas(global_params: Any, deltas: Sequence[Any],
     return jax.tree_util.tree_map(upd, global_params, *deltas)
 
 
+def combine_edges(global_params: Any, edge_params: Sequence[Any],
+                  weights: Sequence[float]) -> Any:
+    """The synchronous hierarchical cloud stage: this round's edge
+    aggregates as size-weighted deltas against the global model
+    (``apply_weighted_deltas``). A single edge aggregate is taken as it is,
+    bitwise — which is what keeps the E = 1 hierarchy equal to the flat run
+    (no f32 round trip through the delta form); none leaves the model as it
+    was."""
+    if len(edge_params) <= 1:
+        return edge_params[0] if edge_params else global_params
+    deltas = [params_delta_f32(p, global_params) for p in edge_params]
+    return apply_weighted_deltas(global_params, deltas,
+                                 jnp.asarray(weights, jnp.float32))
+
+
 def fedavg_stacked(stacked_params: Any, axis_name: Optional[str] = None) -> Any:
     """FedAvg over a leading client axis (the multi-pod 'pod'-axis path).
 

@@ -112,11 +112,10 @@ class TelemetryHook(RoundHook):
         if self.score_components:
             # Pre-selection decomposition: the same component values the
             # selector is about to score this round's cohort with. Pure
-            # jax — consumes no RNG. Flat-async engines expose their
-            # clock-measured staleness; others use the round counter.
-            over = None
-            if hasattr(eng, "_staleness_override"):
-                over = eng._staleness_override()
+            # jax — consumes no RNG. The flat topology under the async
+            # policy measures staleness on the clock; others use the round
+            # counter.
+            over = getattr(eng.topology, "staleness_override", lambda: None)()
             comp = compute_score_components(
                 eng.state, np.int32(ctx.round_idx), self._score_cfg,
                 staleness_override=over)

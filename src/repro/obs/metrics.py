@@ -143,7 +143,7 @@ class MetricsRegistry:
         # clients upload to their edge and only edge aggregates cross the
         # WAN (cloud_uploads is the per-round count of those).
         n_arrivals = int(ctx.num_arrivals) if ctx.num_arrivals else 0
-        cu = getattr(eng, "cloud_uploads", None)
+        cu = getattr(eng.topology, "cloud_uploads", None)
         bytes_down = float(n_dispatched * pb)
         if cu:
             bytes_up_edge = float((n_arrivals or n_dispatched) * pb)
@@ -154,7 +154,7 @@ class MetricsRegistry:
         wire_delta = float(eng.wire_total) - self._prev_wire
         self._prev_wire = float(eng.wire_total)
 
-        rs = getattr(eng, "round_staleness", None)
+        rs = getattr(eng.policy, "round_staleness", None)
         staleness_mean = float(rs[-1]) if rs else 0.0
         n_stragglers = int(ctx.num_stragglers) if ctx.num_stragglers else 0
 
@@ -193,9 +193,10 @@ class MetricsRegistry:
         self.count("bytes_up_edge", bytes_up_edge)
         self.count("bytes_up_wan", bytes_up_wan)
         self.count("wire_bytes", wire_delta)
-        self.gauge("updates_dropped", float(getattr(eng, "updates_dropped", 0)))
+        self.gauge("updates_dropped",
+                   float(getattr(eng.policy, "updates_dropped", 0)))
         self.gauge("stragglers_carried",
-                   float(getattr(eng, "stragglers_carried", 0)))
+                   float(getattr(eng.policy, "stragglers_carried", 0)))
         if rs:
             self.observe("staleness", staleness_mean)
         if extra and "serve_lag" in extra and extra.get("serve_requests", 0):

@@ -309,8 +309,8 @@ class TestDeadlineAndStragglers:
         dispatched = int(res.selected_history.sum())
         aggregated = int(sum(stats.arrivals))
         assert eng.updates_dropped == 0
-        assert len(eng.clock) == int(eng._in_flight.sum())
-        assert dispatched == aggregated + int(eng._in_flight.sum())
+        assert len(eng.clock) == int(eng.policy.in_flight.sum())
+        assert dispatched == aggregated + int(eng.policy.in_flight.sum())
         assert np.isfinite(res.accuracy).all()
         # deadline-paced: round closes never before dispatch+deadline spacing
         assert res.wall_clock[-1] < fed.rounds * 5.0  # ≪ straggler-paced sync

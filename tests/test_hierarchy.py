@@ -244,7 +244,7 @@ class TestAdaptiveBudgetsEngine:
         assert (budgets >= 0).all()
         assert (budgets <= engine.partition.sizes).all()
         # the controller actually observed utilities during the run
-        assert engine._budget_ctl.utilities is not None
+        assert engine.topology.budget_ctl.utilities is not None
         assert np.all(res.selected_history.sum(axis=1)
                       <= hfed.num_selected)
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import FedConfig
 from repro.data import make_vision_data
-from repro.fed import FederatedSpec
+from repro.fed import AsyncConfig, FederatedSpec
 from repro.fed.engine import RoundHook
 from repro.obs.phases import current_recorder, phase, round_step
 from repro.obs.trace import NULL_TRACER, PID_WALL, TraceRecorder, validate_trace_events
@@ -199,3 +199,58 @@ def test_round_syncs_unchanged(monkeypatch):
     # a nested phase is emitted when it closes, before its parent
     assert names == ["select", "gather", "execute", "aggregate", "state_update",
                      "eval"] * 2
+
+
+ROUND_PHASES = ["select", "execute", "aggregate", "state_update", "eval"]
+
+
+@pytest.mark.parametrize("policy", ["sync", "async"])
+@pytest.mark.parametrize("topology", ["flat", "hierarchical"])
+def test_one_round_skeleton_per_regime(profiler, policy, topology):
+    """Every regime runs the one round: select, execute, aggregate,
+    state_update and eval, once each and in that order; a hierarchical
+    round runs its outer and inner selection inside select."""
+    hier = topology == "hierarchical"
+    fed = FedConfig(num_clients=6, participation=0.5, rounds=3, local_epochs=1,
+                    local_batch=4, lr=0.05, mu=0.1, dirichlet_alpha=0.1, seed=0,
+                    round_policy=policy, topology=topology,
+                    edge_count=2 if hier else 0)
+    data = make_vision_data(fed, image_size=8, train_per_class=8, test_per_class=4)
+    kw = {}
+    if policy == "async":  # stragglers that carry over between rounds
+        kw = dict(system=np.asarray([1.0, 3.0, 0.5, 2.5, 1.0, 4.0]),
+                  async_cfg=AsyncConfig(deadline=1.5, over_select_frac=0.5))
+    eng = FederatedSpec(LinearClassifier(), fed, data, selector="heterosel",
+                        steps_per_round=1, **kw).build()
+    rec = eng.tracer = TraceRecorder()
+    eng.run()
+
+    tracks = {e["args"]["name"]: e["tid"] for e in rec.events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    wall = [e for e in rec.events if e["pid"] == PID_WALL and e["ph"] in "BE"]
+    spans = list(zip(wall[::2], wall[1::2]))  # each span is a B, E pair
+    assert all(b["name"] == e["name"] for b, e in spans)
+    top = [(b["args"]["round"], b["name"]) for b, _ in spans
+           if b["tid"] == tracks["round"]]
+    assert top == [(t, name) for t in range(fed.rounds) for name in ROUND_PHASES]
+
+    # the profiler's names, which the chip benchmark's readers key on
+    children, stack = Counter(), []
+    for kind, name, _ in profiler.log:
+        if kind == "enter":
+            children[(stack[-1] if stack else None, name)] += 1
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    for name in ROUND_PHASES:
+        assert children[("fed.round", f"fed.{name}")] == fed.rounds
+    stages = [("fed.select", "fed.outer_select"), ("fed.select", "fed.inner_select")]
+    assert [children[s] for s in stages] == [fed.rounds if hier else 0] * 2
+    if hier:
+        select = {b["args"]["round"]: (b["ts"], e["ts"]) for b, e in spans
+                  if b["name"] == "select"}
+        for b, e in spans:
+            if b["name"] in ("outer_select", "inner_select"):
+                lo, hi = select[b["args"]["round"]]
+                assert b["tid"] == tracks["select"]
+                assert lo <= b["ts"] <= e["ts"] <= hi
